@@ -28,8 +28,7 @@ SPEC_STEPS_PER_TOKEN_BOUND = 0.6
 def _check_spec_decode(serving_rows) -> None:
     """Fail the run when speculative decoding stops paying for itself on
     the repetitive burst, or (worse) when the in-run bit-identity assert
-    did not certify the row - like the telemetry bound, deliberately NOT
-    behind the benchmark try/except."""
+    did not certify the row."""
     on = next(
         (r for r in serving_rows
          if r["name"] == "scheduler_burst/spec_decode_on"), None,
@@ -58,9 +57,9 @@ def _check_spec_decode(serving_rows) -> None:
 
 
 def _check_telemetry_overhead(serving_rows) -> None:
-    """Fail the whole run - deliberately NOT behind the benchmark
-    try/except - when observability costs more than the bound; a silent
-    perf regression in a ride-along layer must not survive a green run."""
+    """Fail the whole run when observability costs more than the bound; a
+    silent perf regression in a ride-along layer must not survive a green
+    run."""
     traced = next(
         (r for r in serving_rows
          if r["name"] == "scheduler_burst/wallclock_traced"), None,
@@ -97,10 +96,16 @@ def _write_json(path: str, rows, label: str) -> None:
 def main() -> None:
     import jax
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     jax.config.update("jax_enable_x64", True)  # fp64 golden references
 
+    from benchmarks import paged_vs_dense as PD
     from benchmarks import paper_tables as T
+    from benchmarks import prefill_prefix as PP
     from benchmarks import roofline_report as R
+    from benchmarks import scheduler_burst as SB
 
     rows = []
     rows += T.fig9a_uniform_mean_sweep()
@@ -110,42 +115,16 @@ def main() -> None:
     rows += T.table4_nan_stats()
     rows += T.real_model_overflow()
     rows += T.kernel_timing()
-    try:
-        from benchmarks import paged_vs_dense as PD
-
-        rows += PD.report()
-    except Exception as e:  # keep run.py total if the serve workload fails
-        print(f"[paged-vs-dense report skipped: {e}]", file=sys.stderr)
-    try:
-        from benchmarks import paged_vs_dense as PD
-
-        _write_json(NUMERICS_JSON, PD.numerics_rows(), "numerics")
-    except Exception as e:
-        print(f"[numerics trajectory skipped: {e}]", file=sys.stderr)
-    try:
-        from benchmarks import prefill_prefix as PP
-
-        rows += PP.report()
-    except Exception as e:  # keep run.py total if the serve workload fails
-        print(f"[prefill-prefix report skipped: {e}]", file=sys.stderr)
-    serving_rows = None
-    try:
-        from benchmarks import scheduler_burst as SB
-
-        rows += SB.report()
-        serving_rows = SB.serving_rows()
-        _write_json(SERVING_JSON, serving_rows, "serving")
-    except Exception as e:
-        print(f"[scheduler-burst report skipped: {e}]", file=sys.stderr)
-    if serving_rows is not None:
-        # acceptance bounds, OUTSIDE the try/except: a violation exits
-        # non-zero instead of degrading into a skipped-report note
-        _check_telemetry_overhead(serving_rows)
-        _check_spec_decode(serving_rows)
-    try:
-        rows += R.report()
-    except Exception as e:  # dry-run artifacts absent on a fresh checkout
-        print(f"[roofline report skipped: {e}]", file=sys.stderr)
+    rows += PD.report()
+    _write_json(NUMERICS_JSON, PD.numerics_rows(), "numerics")
+    rows += PP.report()
+    rows += SB.report()
+    serving_rows = SB.serving_rows()
+    _write_json(SERVING_JSON, serving_rows, "serving")
+    _check_telemetry_overhead(serving_rows)
+    _check_spec_decode(serving_rows)
+    # empty when no dry-run artifacts exist (experiments/dryrun)
+    rows += R.report()
 
     print("\nname,us_per_call,derived")
     for name, us, derived in rows:

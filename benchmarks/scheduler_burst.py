@@ -559,46 +559,53 @@ def _affinity_main():
     print(json.dumps(out))
 
 
-_AFFINITY_CACHE = "unset"
+_AFFINITY_CACHE = None
 
 
 def affinity_metrics():
-    """Run :func:`_affinity_main` in a 2-host-device subprocess; None if
-    the run fails (keeps run.py total on constrained hosts)."""
+    """Run :func:`_affinity_main` on 2 forced host devices (cached)."""
     global _AFFINITY_CACHE
-    if _AFFINITY_CACHE != "unset":
-        return _AFFINITY_CACHE
+    if _AFFINITY_CACHE is None:
+        _AFFINITY_CACHE = _run_on_host_devices("--affinity", 2)
+    return _AFFINITY_CACHE
+
+
+def _run_on_host_devices(flag: str, n_devices: int) -> dict:
+    """Run one phase of this module in a child interpreter with
+    ``n_devices`` forced CPU host devices and return its JSON line.
+
+    These phases exist to exercise multi-device placement on a host with
+    one CPU: XLA pins the host device count at backend start, hence the
+    child.  The child runs on the CPU backend; the parent refuses to start
+    it unless the parent's own backend is the CPU, so a run on an
+    accelerator never reports CPU numbers as its own (and no child ever
+    competes for a chip the parent holds).  A failed child raises."""
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"scheduler_burst {flag} runs on forced CPU host devices in a "
+            f"child process; refusing on the {backend!r} backend"
+        )
     env = dict(
         os.environ,
-        XLA_FLAGS="--xla_force_host_platform_device_count=2",
+        JAX_PLATFORMS="cpu",
+        XLA_FLAGS=f"--xla_force_host_platform_device_count={n_devices}",
         PYTHONPATH=os.pathsep.join(
             [os.path.join(os.path.dirname(__file__), "..", "src"),
              os.path.join(os.path.dirname(__file__), "..")]
             + os.environ.get("PYTHONPATH", "").split(os.pathsep)
         ),
     )
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.scheduler_burst",
-             "--affinity"],
-            env=env, capture_output=True, text=True, timeout=1800,
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmarks.scheduler_burst", flag],
+        env=env, capture_output=True, text=True, timeout=1800,
+    )
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"scheduler_burst {flag} child failed (rc {proc.returncode}):\n"
+            + proc.stderr[-2000:]
         )
-        if proc.returncode == 0:
-            _AFFINITY_CACHE = json.loads(
-                proc.stdout.strip().splitlines()[-1]
-            )
-        else:
-            print(
-                "[scheduler_burst affinity subprocess failed "
-                f"(rc {proc.returncode})]\n" + proc.stderr[-2000:],
-                file=sys.stderr,
-            )
-            _AFFINITY_CACHE = None
-    except Exception as e:
-        print(f"[scheduler_burst affinity subprocess error: {e}]",
-              file=sys.stderr)
-        _AFFINITY_CACHE = None
-    return _AFFINITY_CACHE
+    return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
 # --------------------------------------------------- multi-device burst --
@@ -685,45 +692,14 @@ def _multidev_main():
     }))
 
 
-_MULTIDEV_CACHE = "unset"
+_MULTIDEV_CACHE = None
 
 
 def multidev_metrics():
-    """Run :func:`_multidev_main` in an 8-host-device subprocess; None if
-    the run fails (keeps run.py total on constrained hosts)."""
+    """Run :func:`_multidev_main` on 8 forced host devices (cached)."""
     global _MULTIDEV_CACHE
-    if _MULTIDEV_CACHE != "unset":
-        return _MULTIDEV_CACHE
-    env = dict(
-        os.environ,
-        XLA_FLAGS="--xla_force_host_platform_device_count=8",
-        PYTHONPATH=os.pathsep.join(
-            [os.path.join(os.path.dirname(__file__), "..", "src"),
-             os.path.join(os.path.dirname(__file__), "..")]
-            + os.environ.get("PYTHONPATH", "").split(os.pathsep)
-        ),
-    )
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.scheduler_burst",
-             "--multidev"],
-            env=env, capture_output=True, text=True, timeout=1800,
-        )
-        if proc.returncode == 0:
-            _MULTIDEV_CACHE = json.loads(proc.stdout.strip().splitlines()[-1])
-        else:
-            # surface the failure: a broken bit-identity assertion must
-            # not be indistinguishable from a constrained host
-            print(
-                "[scheduler_burst multidev subprocess failed "
-                f"(rc {proc.returncode})]\n" + proc.stderr[-2000:],
-                file=sys.stderr,
-            )
-            _MULTIDEV_CACHE = None
-    except Exception as e:
-        print(f"[scheduler_burst multidev subprocess error: {e}]",
-              file=sys.stderr)
-        _MULTIDEV_CACHE = None
+    if _MULTIDEV_CACHE is None:
+        _MULTIDEV_CACHE = _run_on_host_devices("--multidev", 8)
     return _MULTIDEV_CACHE
 
 
@@ -785,28 +761,26 @@ def report():
         f"flood throttled by quota | streams bit-identical blind vs tenant",
     ))
     af = affinity_metrics()
-    if af is not None:
-        rows.append((
-            "scheduler_burst_prefix_affinity_2rep", 0.0,
-            f"affinity: mean TTFT {af['affinity']['mean_ttft_steps']:.1f} "
-            f"steps, hit rate {af['affinity']['cache_hit_rate']:.2f}, "
-            f"{af['affinity']['burst_on_warm_replica']}/{af['burst_size']} "
-            f"on the warm replica | rr: "
-            f"{af['rr']['mean_ttft_steps']:.1f} steps, hit rate "
-            f"{af['rr']['cache_hit_rate']:.2f} | streams identical",
-        ))
+    rows.append((
+        "scheduler_burst_prefix_affinity_2rep", 0.0,
+        f"affinity: mean TTFT {af['affinity']['mean_ttft_steps']:.1f} "
+        f"steps, hit rate {af['affinity']['cache_hit_rate']:.2f}, "
+        f"{af['affinity']['burst_on_warm_replica']}/{af['burst_size']} "
+        f"on the warm replica | rr: "
+        f"{af['rr']['mean_ttft_steps']:.1f} steps, hit rate "
+        f"{af['rr']['cache_hit_rate']:.2f} | streams identical",
+    ))
     md = multidev_metrics()
-    if md is not None:
-        ratio = md["pool_bytes_per_replica"] / md["pool_bytes_per_device"]
-        rows.append((
-            "scheduler_burst_multidev_2x4", 0.0,
-            f"mean TTFT {md['mean_ttft_steps']:.1f} steps "
-            f"(worst {md['max_ttft_steps']}) | "
-            f"{md['replicas']} replicas x model={md['model_shards']} | "
-            f"per-device pool {md['pool_bytes_per_device'] / 1e3:.1f} kB = "
-            f"1/{ratio:.1f} of the replica pool | streams bit-identical "
-            "to the 1-device serve",
-        ))
+    ratio = md["pool_bytes_per_replica"] / md["pool_bytes_per_device"]
+    rows.append((
+        "scheduler_burst_multidev_2x4", 0.0,
+        f"mean TTFT {md['mean_ttft_steps']:.1f} steps "
+        f"(worst {md['max_ttft_steps']}) | "
+        f"{md['replicas']} replicas x model={md['model_shards']} | "
+        f"per-device pool {md['pool_bytes_per_device'] / 1e3:.1f} kB = "
+        f"1/{ratio:.1f} of the replica pool | streams bit-identical "
+        "to the 1-device serve",
+    ))
     return rows
 
 
@@ -901,38 +875,36 @@ def serving_rows():
         },
     })
     af = affinity_metrics()
-    if af is not None:
-        out.append({
-            "name": "scheduler_burst/prefix_affinity_2rep",
-            "mesh": {"data": AFFINITY_MESH[0], "model": AFFINITY_MESH[1]},
-            "affinity": af["affinity"],
-            "rr": af["rr"],
-            "streams_identical_across_routing": True,
-            "workload": {
-                "system_tokens": af["system_tokens"],
-                "tail_tokens": AFFINITY_TAIL,
-                "burst": af["burst_size"], "gen": GEN, "page": PAGE,
-                "chunk": CHUNK, "batch": BATCH,
-            },
-        })
+    out.append({
+        "name": "scheduler_burst/prefix_affinity_2rep",
+        "mesh": {"data": AFFINITY_MESH[0], "model": AFFINITY_MESH[1]},
+        "affinity": af["affinity"],
+        "rr": af["rr"],
+        "streams_identical_across_routing": True,
+        "workload": {
+            "system_tokens": af["system_tokens"],
+            "tail_tokens": AFFINITY_TAIL,
+            "burst": af["burst_size"], "gen": GEN, "page": PAGE,
+            "chunk": CHUNK, "batch": BATCH,
+        },
+    })
     md = multidev_metrics()
-    if md is not None:
-        out.append({
-            "name": "scheduler_burst/multidev_2x4",
-            "mesh": {"data": md["replicas"], "model": md["model_shards"]},
-            "mean_ttft_steps": md["mean_ttft_steps"],
-            "max_ttft_steps": md["max_ttft_steps"],
-            "drain_steps": md["drain_steps"],
-            "pool_bytes_per_replica": md["pool_bytes_per_replica"],
-            "pool_bytes_per_device": md["pool_bytes_per_device"],
-            "bit_identical_to_1dev": md["bit_identical_to_1dev"],
-            "async_bit_identical": md.get("async_bit_identical", False),
-            "workload": {
-                "prompts": list(PROMPTS), "gen": GEN, "page": PAGE,
-                "chunk": CHUNK, "batch": BATCH,
-                "arrival_gap": ARRIVAL_GAP,
-            },
-        })
+    out.append({
+        "name": "scheduler_burst/multidev_2x4",
+        "mesh": {"data": md["replicas"], "model": md["model_shards"]},
+        "mean_ttft_steps": md["mean_ttft_steps"],
+        "max_ttft_steps": md["max_ttft_steps"],
+        "drain_steps": md["drain_steps"],
+        "pool_bytes_per_replica": md["pool_bytes_per_replica"],
+        "pool_bytes_per_device": md["pool_bytes_per_device"],
+        "bit_identical_to_1dev": md["bit_identical_to_1dev"],
+        "async_bit_identical": md.get("async_bit_identical", False),
+        "workload": {
+            "prompts": list(PROMPTS), "gen": GEN, "page": PAGE,
+            "chunk": CHUNK, "batch": BATCH,
+            "arrival_gap": ARRIVAL_GAP,
+        },
+    })
     return out
 
 

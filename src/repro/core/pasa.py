@@ -28,7 +28,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import beta as beta_lib
-from repro.core.precision import FP16, FP32, PrecisionPolicy, reduce_dtype
+from repro.core.precision import (
+    FP16,
+    FP32,
+    PrecisionPolicy,
+    operand_precision,
+    reduce_dtype,
+)
 from repro.core.shifting import (
     effective_invariance,
     shift_kv_blocks,
@@ -145,7 +151,8 @@ def update_state(
 
     # -- line 11: S'_ij = Q_i K'_j^T, stored at score precision. ------------
     s = jnp.einsum(
-        "...sd,...td->...st", q, k_shifted, preferred_element_type=gemm_t
+        "...sd,...td->...st", q, k_shifted, preferred_element_type=gemm_t,
+        precision=operand_precision(q.dtype),
     ).astype(policy.score_dtype)
     if post_scale != 1.0:
         # Plain-FA path (Eq. 2): static scaling happens on the vector unit
@@ -231,7 +238,8 @@ def update_state(
             jnp.swapaxes(col_live, -1, -2), v, jnp.asarray(0.0, v.dtype)
         )
     pv = jnp.einsum(
-        "...st,...td->...sd", p, v.astype(p.dtype), preferred_element_type=gemm_t
+        "...st,...td->...sd", p, v.astype(p.dtype), preferred_element_type=gemm_t,
+        precision=operand_precision(p.dtype),
     ).astype(policy.acc_dtype)
     acc_new = (
         e_prev.astype(policy.acc_dtype) * state.acc

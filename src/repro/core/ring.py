@@ -78,9 +78,7 @@ def ring_pasa_attention(
     s2_loc = k.shape[-2]
     if s2_loc % block_kv:
         raise ValueError(f"local KV len {s2_loc} % block_kv {block_kv} != 0")
-    from repro.compat import axis_size
-
-    n_dev = axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
 
     q = q.astype(policy.input_dtype)
@@ -166,12 +164,10 @@ def make_ring_attention(mesh, axis_name: str, **kw):
     the caller's enclosing jit)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     spec = P(None, None, axis_name, None)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+        jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
         out_specs=spec, check_vma=False,
     )
     def fn(q, k, v):

@@ -19,6 +19,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.precision import operand_precision
+
 
 def shifting_matrix(s2: int, d: int, beta: float, dtype=jnp.float16) -> jnp.ndarray:
     """Build M in ``dtype`` exactly as the paper stores it (fp16 on-chip).
@@ -114,7 +116,7 @@ def shift_kv_blocks(k: jnp.ndarray, m: jnp.ndarray, block_kv: int) -> jnp.ndarra
     acc_t = jnp.float64 if m.dtype == jnp.float64 else jnp.float32
     out = jnp.einsum(
         "st,...jtd->...jsd", m, kb.astype(m.dtype),
-        preferred_element_type=acc_t,
+        preferred_element_type=acc_t, precision=operand_precision(m.dtype),
     ).astype(m.dtype)
     return out.reshape(*lead, s2, dd)
 

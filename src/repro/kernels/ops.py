@@ -407,8 +407,6 @@ def pasa_paged_decode_sharded(
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     qspec = P(None, axis, None, None)
     pspec = P(None, None, axis, None)
     in_specs = [qspec, pspec, pspec, P(None, None), P(None)]
@@ -425,7 +423,7 @@ def pasa_paged_decode_sharded(
             **dict(zip(names, quant)),
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh, in_specs=tuple(in_specs), out_specs=qspec,
         check_vma=False,
     )
@@ -480,8 +478,6 @@ def pasa_paged_prefill_sharded(
     if kvh % msize == 0 and h % msize == 0:
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
-
         qspec = P(None, axis, None, None)
         pspec = P(None, None, axis, None)
         in_specs = [qspec, pspec, pspec, P(None, None), P(None), P(None)]
@@ -498,7 +494,7 @@ def pasa_paged_prefill_sharded(
                 **dict(zip(names, quant)),
             )
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh, in_specs=tuple(in_specs), out_specs=qspec,
             check_vma=False,
         )
@@ -570,7 +566,6 @@ def _paged_prefill_ring(
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.core.ring import ring_pasa_attention
 
     seq_spec = P(None, None, None, axis, None)
@@ -582,7 +577,7 @@ def _paged_prefill_ring(
             block_kv=page, causal=True, kv_len=kl, q_offset=ro,
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(seq_spec, seq_spec, seq_spec, rep, rep),
         out_specs=seq_spec, check_vma=False,

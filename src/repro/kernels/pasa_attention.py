@@ -34,7 +34,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.precision import reduce_dtype
-from repro.kernels.compat import CompilerParams
+from repro.kernels.fp16 import KernelNumerics
+from repro.kernels.pasa_decode import accumulate, online_update
 
 NEG_BIG = -30000.0
 _LANES = 128
@@ -54,6 +55,8 @@ def _attn_kernel(
     stat_dtype,
     acc_dtype,
     score_dtype,
+    out_dtype,
+    num: KernelNumerics,
 ):
     i = pl.program_id(1)
     j = pl.program_id(2)
@@ -63,7 +66,7 @@ def _attn_kernel(
         m_scr[...] = jnp.full_like(m_scr, NEG_BIG)
         l_scr[...] = jnp.zeros_like(l_scr)
         f_scr[...] = jnp.zeros_like(f_scr)
-        cnt_scr[...] = jnp.zeros_like(cnt_scr)
+        cnt_scr[0, 0] = 0
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     # Causal block skipping: block (i, j) is dead iff its first row cannot see
@@ -79,12 +82,15 @@ def _attn_kernel(
         k = k_ref[0]          # (bkv, d)  (already PASA-shifted + scaled)
         v = v_ref[0]          # (bkv, d)
 
-        s = jax.lax.dot_general(
+        def fit_stat(x):
+            return num.fit(x, stat_dtype)
+
+        s = num.fit(jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(score_dtype)                      # (bq, bkv)
+            preferred_element_type=jnp.float32, precision=num.precision,
+        ), score_dtype)                            # (bq, bkv)
         if post_scale != 1.0:
-            s = s * jnp.asarray(post_scale, s.dtype)
+            s = num.fit(s * num.const(post_scale, score_dtype), score_dtype)
 
         # Row pseudo-average of the full (unmasked) block - Eq. 14 requires
         # the mean over exactly the columns the shift used.  Reductions
@@ -94,12 +100,12 @@ def _attn_kernel(
         # as pasa_decode.masked_block_update).
         wide = reduce_dtype(stat_dtype)
         ones = jnp.ones((block_kv, 1), wide)
-        sbar = (
+        sbar = fit_stat(
             jax.lax.dot_general(
                 s.astype(wide), ones, (((1,), (0,)), ((), ())),
-                preferred_element_type=wide,
+                preferred_element_type=wide, precision=num.precision,
             ) / block_kv
-        ).astype(stat_dtype)
+        )
 
         if causal:
             rows = i * block_q + jax.lax.broadcasted_iota(
@@ -110,45 +116,30 @@ def _attn_kernel(
             )
             s = jnp.where(rows >= cols, s, jnp.asarray(NEG_BIG, s.dtype))
 
-        m_loc = jnp.max(s.astype(stat_dtype), axis=-1, keepdims=True)
-        p = jnp.exp(s.astype(stat_dtype) - m_loc).astype(score_dtype)
-        l_loc = jax.lax.dot_general(
-            p.astype(wide), ones, (((1,), (0,)), ((), ())),
-            preferred_element_type=wide,
-        ).astype(stat_dtype)
-
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        cnt = cnt_scr[0, 0]
-        first = cnt == 0
-
-        if inva != 0.0:
-            f_prev = f_scr[:, :1]
-            cntf = cnt.astype(stat_dtype)
-            f_new = (cntf * f_prev + sbar) / (cntf + 1.0)
-            dm_prev_c = jnp.asarray(inva, stat_dtype) * (f_prev - f_new)
-            dm_cur_c = jnp.asarray(inva, stat_dtype) * (sbar - f_new)
-            f_scr[...] = jnp.broadcast_to(f_new, f_scr.shape)
-        else:
-            dm_prev_c = jnp.zeros_like(m_prev)
-            dm_cur_c = jnp.zeros_like(m_loc)
-
-        cand_prev = jnp.where(
-            first, jnp.asarray(NEG_BIG, stat_dtype), m_prev + dm_prev_c
+        s_stat = fit_stat(s)
+        m_loc = jnp.max(
+            s_stat.astype(num.storage(stat_dtype)), axis=-1, keepdims=True
         )
-        m_new = jnp.maximum(cand_prev, m_loc + dm_cur_c)
-        e_prev = jnp.exp(cand_prev - m_new)
-        e_cur = jnp.exp(m_loc + dm_cur_c - m_new)
+        p = num.fit(jnp.exp(fit_stat(s_stat - m_loc)), score_dtype)
+        l_loc = fit_stat(jax.lax.dot_general(
+            p.astype(wide), ones, (((1,), (0,)), ((), ())),
+            preferred_element_type=wide, precision=num.precision,
+        ))
 
-        l_new = e_prev * l_prev + e_cur * l_loc
+        cnt = cnt_scr[0, 0]
+        m_new, l_new, f_new, e_prev, e_cur = online_update(
+            m_scr[:, :1], l_scr[:, :1], f_scr[:, :1], cnt, m_loc, l_loc,
+            sbar, inva=inva, stat_dtype=stat_dtype, num=num,
+        )
+        if inva != 0.0:
+            f_scr[...] = jnp.broadcast_to(f_new, f_scr.shape)
 
-        pv = jax.lax.dot_general(
-            p, v.astype(p.dtype), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(acc_dtype)
-        acc_scr[...] = (
-            e_prev.astype(acc_dtype) * acc_scr[...]
-            + e_cur.astype(acc_dtype) * pv
+        pv = num.fit(jax.lax.dot_general(
+            p, num.fit(v, score_dtype), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=num.precision,
+        ), acc_dtype)
+        acc_scr[...] = accumulate(
+            acc_scr[...], e_prev, e_cur, pv, acc_dtype=acc_dtype, num=num
         )
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
@@ -156,8 +147,8 @@ def _attn_kernel(
 
     @pl.when(j == n_kv - 1)
     def _fin():
-        l = l_scr[:, :1].astype(acc_dtype)
-        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        l = num.fit(l_scr[:, :1], acc_dtype)
+        o_ref[0] = num.fit(acc_scr[...] / l, out_dtype)
 
 
 @functools.partial(
@@ -195,7 +186,8 @@ def attention_kernel_call(
         )
     n_q, n_kv = s1 // block_q, s2 // block_kv
 
-    qr = q.reshape(b * h, s1, d)
+    num = KernelNumerics(emulate_f16=not interpret)
+    qr = num.to_storage(q.reshape(b * h, s1, d))
 
     def q_map(bh, i, j):
         return (bh, i, 0)
@@ -205,15 +197,17 @@ def attention_kernel_call(
         kh = (bh % h) // group
         return (bb * kvh + kh, j, 0)
 
-    kr = k_shifted.reshape(b * kvh, s2, d)
-    vr = v.reshape(b * kvh, s2, d)
+    kr = num.to_storage(k_shifted.reshape(b * kvh, s2, d))
+    vr = num.to_storage(v.reshape(b * kvh, s2, d))
 
     kernel = functools.partial(
         _attn_kernel,
         inva=inva, post_scale=post_scale, causal=causal,
         block_q=block_q, block_kv=block_kv, n_kv=n_kv,
         stat_dtype=stat_dtype, acc_dtype=acc_dtype, score_dtype=score_dtype,
+        out_dtype=out_dtype, num=num,
     )
+    stat = num.storage(stat_dtype)
 
     out = pl.pallas_call(
         kernel,
@@ -224,17 +218,19 @@ def attention_kernel_call(
             pl.BlockSpec((1, block_kv, d), kv_map),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), q_map),
-        out_shape=jax.ShapeDtypeStruct((b * h, s1, d), out_dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            (b * h, s1, d), num.storage(out_dtype)
+        ),
         scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), stat_dtype),   # m
-            pltpu.VMEM((block_q, _LANES), stat_dtype),   # l
-            pltpu.VMEM((block_q, _LANES), stat_dtype),   # f (global pseudo-avg)
-            pltpu.SMEM((1, 1), jnp.int32),               # processed-block count
-            pltpu.VMEM((block_q, d), acc_dtype),         # output accumulator
+            pltpu.VMEM((block_q, _LANES), stat),   # m
+            pltpu.VMEM((block_q, _LANES), stat),   # l
+            pltpu.VMEM((block_q, _LANES), stat),   # f (global pseudo-avg)
+            pltpu.SMEM((1, 1), jnp.int32),         # processed-block count
+            pltpu.VMEM((block_q, d), num.storage(acc_dtype)),  # accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(qr, kr, vr)
-    return out.reshape(b, h, s1, d)
+    return out.reshape(b, h, s1, d).astype(out_dtype)

@@ -46,20 +46,73 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-from repro.kernels.pasa_decode import init_decode_scratch, masked_block_update
+from repro.kernels.fp16 import KernelNumerics
+from repro.kernels.pasa_decode import (
+    decode_scratch,
+    init_decode_scratch,
+    masked_block_update,
+)
 
-_LANES = 128
 
-
-def dequant_block(codes, scale, shift, deq_dtype):
+def dequant_block(codes, scale, shift, deq_dtype, num: KernelNumerics):
     """VMEM dequantization: (page, D) codes x scalar scale x (1, D) shift
     -> (page, D) values at the kernels' input dtype.  Element-wise and
     deterministic, so the Pallas kernels and the XLA gather fallbacks
     produce bit-identical dequantized values from the same page bytes."""
-    return (
-        codes.astype(jnp.float32) * scale + shift
-    ).astype(deq_dtype)
+    return num.fit(codes.astype(jnp.float32) * scale + shift, deq_dtype)
+
+
+def pool_blocks(k_pages, v_pages, quant, page_map):
+    """Lay the pool and its sidecars out for lane-aligned page blocks.
+
+    The pool (P, page, KVH, D) is viewed as (P, page, KVH * D), so one
+    (page, D) block of kv head ``h`` is lane block ``h`` - a slice the TPU
+    tiling accepts (a block of 1 along the KVH axis would sit in the
+    second-minor position).  Scales (P, KVH) become (P, 1, KVH) rows read
+    whole and picked by :func:`head_scale`; shifts (P, KVH, D) become
+    (P, 1, KVH * D) with the same lane-block index as the pages.
+    ``page_map(*grid_and_prefetch) -> (physical page, kv head)``.
+    Returns (in_specs, inputs) for the K/V blocks and, when ``quant`` is
+    given, the k_scale/k_shift/v_scale/v_shift blocks.
+    """
+    p, page, kvh, d = k_pages.shape
+
+    def kv_map(*idx):
+        pg, h = page_map(*idx)
+        return (pg, 0, h)
+
+    def sc_map(*idx):
+        pg, _ = page_map(*idx)
+        return (pg, 0, 0)
+
+    kv_spec = pl.BlockSpec((1, page, d), kv_map)
+    in_specs = [kv_spec, kv_spec]
+    inputs = [
+        k_pages.reshape(p, page, kvh * d), v_pages.reshape(p, page, kvh * d)
+    ]
+    if quant:
+        sc_spec = pl.BlockSpec((1, 1, kvh), sc_map)
+        sh_spec = pl.BlockSpec((1, 1, d), kv_map)
+        in_specs += [sc_spec, sh_spec, sc_spec, sh_spec]
+        inputs += [
+            quant["k_scale"].reshape(p, 1, kvh),
+            quant["k_shift"].reshape(p, 1, kvh * d),
+            quant["v_scale"].reshape(p, 1, kvh),
+            quant["v_shift"].reshape(p, 1, kvh * d),
+        ]
+    return in_specs, inputs
+
+
+def head_scale(row, h):
+    """Scale of kv head ``h`` from a (1, KVH) sidecar row, as (1, 1).
+
+    A masked lane sum: exactly one term is non-zero, so the pick is
+    exact."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.sum(
+        jnp.where(lane == h, row, 0.0), axis=-1, keepdims=True,
+        dtype=jnp.float32,
+    )
 
 
 def _paged_decode_kernel(
@@ -73,17 +126,20 @@ def _paged_decode_kernel(
     stat_dtype,
     acc_dtype,
     score_dtype,
+    out_dtype,
     quantized: bool,
     deq_dtype,
+    num: KernelNumerics,
 ):
     if quantized:
-        # (1,1,G,D), (1,page,1,D) codes x2, (1,1) scale x2, (1,1,D) shift x2
+        # (1,1,G,D), (1,page,D) codes x2, (1,1,KVH) scale x2, (1,1,D) shift x2
         (q_ref, k_ref, v_ref, ks_ref, kh_ref, vs_ref, vh_ref,
          o_ref, m_scr, l_scr, f_scr, cnt_scr, acc_scr) = refs
     else:
         (q_ref, k_ref, v_ref,
          o_ref, m_scr, l_scr, f_scr, cnt_scr, acc_scr) = refs
     b = pl.program_id(0)
+    h = pl.program_id(1)
     j = pl.program_id(2)
     kv_len = kv_len_ref[b]
 
@@ -95,25 +151,28 @@ def _paged_decode_kernel(
     def _step():
         # One page == one PASA block: the shared block update (the SAME
         # code the contiguous decode kernel runs, see pasa_decode.py) with
-        # the page's global column offset.  Only the ref slicing differs -
-        # the pool layout carries the head dim third.
-        k = k_ref[0, :, 0, :]
-        v = v_ref[0, :, 0, :]
+        # the page's global column offset.
+        k = k_ref[0]
+        v = v_ref[0]
         if quantized:
-            k = dequant_block(k, ks_ref[0, 0], kh_ref[0], deq_dtype)
-            v = dequant_block(v, vs_ref[0, 0], vh_ref[0], deq_dtype)
+            k = dequant_block(
+                k, head_scale(ks_ref[0], h), kh_ref[0], deq_dtype, num
+            )
+            v = dequant_block(
+                v, head_scale(vs_ref[0], h), vh_ref[0], deq_dtype, num
+            )
         masked_block_update(
             q_ref[0, 0], k, v,
             kv_len, j * page_size, page_size,
             m_scr, l_scr, f_scr, cnt_scr, acc_scr,
-            inva=inva, beta=beta, stat_dtype=stat_dtype,
-            acc_dtype=acc_dtype, score_dtype=score_dtype,
+            inva=inva, beta=beta, in_dtype=deq_dtype, stat_dtype=stat_dtype,
+            acc_dtype=acc_dtype, score_dtype=score_dtype, num=num,
         )
 
     @pl.when(j == n_pages - 1)
     def _fin():
-        l = l_scr[:, :1].astype(acc_dtype)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        l = num.fit(l_scr[:, :1], acc_dtype)
+        o_ref[0, 0] = num.fit(acc_scr[...] / l, out_dtype)
 
 
 @functools.partial(
@@ -147,35 +206,31 @@ def paged_decode_kernel_call(
     _, page_size, _, _ = k_pages.shape
     n_pages = page_table.shape[1]
     quantized = k_scale is not None
+    num = KernelNumerics(emulate_f16=not interpret)
 
     kernel = functools.partial(
         _paged_decode_kernel,
         inva=inva, beta=beta, page_size=page_size, n_pages=n_pages,
         stat_dtype=stat_dtype, acc_dtype=acc_dtype, score_dtype=score_dtype,
-        quantized=quantized, deq_dtype=deq_dtype,
+        out_dtype=out_dtype, quantized=quantized, deq_dtype=deq_dtype,
+        num=num,
     )
 
     # The page gather: physical page id read from the prefetched table
-    # inside the index map, before the DMA is issued.
-    kv_map = lambda b_, h, j, kvl, pt: (pt[b_, j], 0, h, 0)
+    # inside the index map, before the DMA is issued.  Sidecars ride the
+    # same gather; one (scalar, vector) pair per (page, kv-head).
+    quant = (
+        dict(k_scale=k_scale, k_shift=k_shift, v_scale=v_scale,
+             v_shift=v_shift) if quantized else None
+    )
+    kv_specs, kv_inputs = pool_blocks(
+        num.to_storage(k_pages), num.to_storage(v_pages), quant,
+        lambda b_, h, j, kvl, pt: (pt[b_, j], h),
+    )
     in_specs = [
         pl.BlockSpec((1, 1, g, d), lambda b_, h, j, kvl, pt: (b_, h, 0, 0)),
-        pl.BlockSpec((1, page_size, 1, d), kv_map),
-        pl.BlockSpec((1, page_size, 1, d), kv_map),
-    ]
-    inputs = [q, k_pages, v_pages]
-    if quantized:
-        # Sidecars ride the same page-table gather; one (scalar, vector)
-        # pair per (page, kv-head).
-        sc_map = lambda b_, h, j, kvl, pt: (pt[b_, j], h)
-        sh_map = lambda b_, h, j, kvl, pt: (pt[b_, j], h, 0)
-        in_specs += [
-            pl.BlockSpec((1, 1), sc_map),
-            pl.BlockSpec((1, 1, d), sh_map),
-            pl.BlockSpec((1, 1), sc_map),
-            pl.BlockSpec((1, 1, d), sh_map),
-        ]
-        inputs += [k_scale, k_shift, v_scale, v_shift]
+    ] + kv_specs
+    inputs = [num.to_storage(q)] + kv_inputs
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -184,20 +239,14 @@ def paged_decode_kernel_call(
         out_specs=pl.BlockSpec(
             (1, 1, g, d), lambda b_, h, j, kvl, pt: (b_, h, 0, 0)
         ),
-        scratch_shapes=[
-            pltpu.VMEM((g, _LANES), stat_dtype),
-            pltpu.VMEM((g, _LANES), stat_dtype),
-            pltpu.VMEM((g, _LANES), stat_dtype),
-            pltpu.SMEM((1, 1), jnp.int32),
-            pltpu.VMEM((g, d), acc_dtype),
-        ],
+        scratch_shapes=decode_scratch(g, d, stat_dtype, acc_dtype, num),
     )
 
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), out_dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), num.storage(out_dtype)),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -205,7 +254,7 @@ def paged_decode_kernel_call(
         kv_len.astype(jnp.int32), page_table.astype(jnp.int32),
         *inputs,
     )
-    return out
+    return out.astype(out_dtype)
 
 
 def _gather_dequant(pages, scale, shift, page_table, deq_dtype):

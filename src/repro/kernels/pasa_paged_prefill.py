@@ -64,8 +64,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.precision import reduce_dtype
-from repro.kernels.compat import CompilerParams
-from repro.kernels.pasa_paged_decode import _gather_dequant, dequant_block
+from repro.kernels.fp16 import KernelNumerics
+from repro.kernels.pasa_decode import accumulate, online_update
+from repro.kernels.pasa_paged_decode import (
+    _gather_dequant,
+    dequant_block,
+    head_scale,
+    pool_blocks,
+)
 
 NEG_BIG = -30000.0
 _LANES = 128
@@ -82,9 +88,11 @@ def _chunk_block_update(
     *,
     inva: float,
     beta: float,
+    in_dtype,
     stat_dtype,
     acc_dtype,
     score_dtype,
+    num: KernelNumerics,
 ):
     """Fold one page into the per-row running state (chunk-exact rules).
 
@@ -100,10 +108,14 @@ def _chunk_block_update(
     (tests/test_prefix_cache.py) instead of merely tolerance-close.  The
     decode kernels' partner is their paged/contiguous twin across memory
     layouts, hence their ones-vector ``dot_general`` spelling.
+    ``num`` holds the policy dtypes (kernels/fp16.py).
     """
     d = q.shape[-1]
     wide = reduce_dtype(stat_dtype)
     scale = jnp.asarray(1.0 / np.sqrt(d), wide)
+
+    def fit_stat(x):
+        return num.fit(x, stat_dtype)
 
     cols = col0 + jax.lax.broadcasted_iota(jnp.int32, (page, 1), 0)
     valid = cols < kv_len                                   # (page, 1)
@@ -114,76 +126,59 @@ def _chunk_block_update(
         km = jnp.sum(
             jnp.where(valid, k.astype(wide), 0.0), axis=0, keepdims=True,
         ) / count                                           # (1, d)
-        k_sh = (
-            (k.astype(wide) - jnp.asarray(beta, wide) * km) * scale
-        ).astype(k.dtype)
+        k_sh = num.fit(
+            (k.astype(wide) - jnp.asarray(beta, wide) * km) * scale, in_dtype
+        )
     else:
         k_sh = k
 
-    s = jax.lax.dot_general(
+    s = num.fit(jax.lax.dot_general(
         q, k_sh, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(score_dtype)                                   # (bq, page)
+        preferred_element_type=jnp.float32, precision=num.precision,
+    ), score_dtype)                                         # (bq, page)
     if beta == 0.0:
         # Plain-FA path (Eq. 2), mirroring the XLA fallback's update_state:
         # raw QK^T is stored at score precision (the paper's overflow point)
         # and the static 1/sqrt(d) lands after, on the vector unit.
-        s = s * jnp.asarray(1.0 / np.sqrt(d), s.dtype)
+        s = num.fit(s * num.const(1.0 / np.sqrt(d), score_dtype), score_dtype)
 
     vmask = valid[:, 0][None, :]                            # (1, page)
     # Row pseudo-average over the VALID columns (same set the shift used);
     # the causal mask has not been applied yet - chunk-exact semantics.
-    sbar = (
+    sbar = fit_stat(
         jnp.sum(jnp.where(vmask, s.astype(wide), 0.0), axis=-1,
                 keepdims=True) / count
-    ).astype(stat_dtype)
+    )
 
     rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
     causal = rows >= jnp.transpose(cols)                    # (bq, page)
     mask = jnp.logical_and(causal, vmask)
     s = jnp.where(mask, s, jnp.asarray(NEG_BIG, s.dtype))
 
-    m_loc = jnp.max(s.astype(stat_dtype), axis=-1, keepdims=True)
-    p = jnp.exp(s.astype(stat_dtype) - m_loc).astype(score_dtype)
-    p = jnp.where(mask, p, jnp.asarray(0.0, p.dtype))
-    l_loc = jnp.sum(
-        p.astype(wide), axis=-1, keepdims=True
-    ).astype(stat_dtype)
-
-    m_prev = m_scr[:, :1]
-    l_prev = l_scr[:, :1]
-    cnt = cnt_scr[:, :1]                                    # per-row (bq, 1)
-    first = cnt == 0.0
-
-    if inva != 0.0:
-        f_prev = f_scr[:, :1]
-        f_new = (cnt * f_prev + sbar) / (cnt + 1.0)
-        dm_prev_c = jnp.asarray(inva, stat_dtype) * (f_prev - f_new)
-        dm_cur_c = jnp.asarray(inva, stat_dtype) * (sbar - f_new)
-    else:
-        f_new = f_scr[:, :1]
-        dm_prev_c = jnp.zeros_like(m_prev)
-        dm_cur_c = jnp.zeros_like(m_loc)
-
-    cand_prev = jnp.where(
-        first, jnp.asarray(NEG_BIG, stat_dtype), m_prev + dm_prev_c
+    s_stat = fit_stat(s)
+    m_loc = jnp.max(
+        s_stat.astype(num.storage(stat_dtype)), axis=-1, keepdims=True
     )
-    m_new = jnp.maximum(cand_prev, m_loc + dm_cur_c)
-    e_prev = jnp.exp(cand_prev - m_new)
-    e_cur = jnp.exp(m_loc + dm_cur_c - m_new)
-    l_new = e_prev * l_prev + e_cur * l_loc
+    p = num.fit(jnp.exp(fit_stat(s_stat - m_loc)), score_dtype)
+    p = jnp.where(mask, p, jnp.asarray(0.0, p.dtype))
+    l_loc = fit_stat(jnp.sum(p.astype(wide), axis=-1, keepdims=True))
+
+    m_new, l_new, f_new, e_prev, e_cur = online_update(
+        m_scr[:, :1], l_scr[:, :1], f_scr[:, :1],
+        cnt_scr[:, :1],                                     # per-row (bq, 1)
+        m_loc, l_loc, sbar, inva=inva, stat_dtype=stat_dtype, num=num,
+    )
 
     # Zero v at INVALID columns before the PV GEMM (0 * NaN protection for
     # stale page contents); causally-masked-but-valid columns hold real
     # finite K/V and are already nulled through p == 0.
     v_live = jnp.where(valid, v, jnp.asarray(0.0, v.dtype))
-    pv = jax.lax.dot_general(
-        p, v_live.astype(p.dtype), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(acc_dtype)
-    acc_new = (
-        e_prev.astype(acc_dtype) * acc_scr[...]
-        + e_cur.astype(acc_dtype) * pv
+    pv = num.fit(jax.lax.dot_general(
+        p, num.fit(v_live, score_dtype), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=num.precision,
+    ), acc_dtype)
+    acc_new = accumulate(
+        acc_scr[...], e_prev, e_cur, pv, acc_dtype=acc_dtype, num=num
     )
 
     # Per-row dead-page no-op: rows with no causally-visible valid column
@@ -212,17 +207,20 @@ def _paged_prefill_kernel(
     inva: float,
     beta: float,
     n_heads: int,
+    group: int,
     block_q: int,
     page_size: int,
     n_pages: int,
     stat_dtype,
     acc_dtype,
     score_dtype,
+    out_dtype,
     quantized: bool,
     deq_dtype,
+    num: KernelNumerics,
 ):
     if quantized:
-        # (1,bq,D), (1,page,1,D) codes x2, (1,1) scale x2, (1,1,D) shift x2
+        # (1,bq,D), (1,page,D) codes x2, (1,1,KVH) scale x2, (1,1,D) shift x2
         (q_ref, k_ref, v_ref, ks_ref, kh_ref, vs_ref, vh_ref,
          o_ref, m_scr, l_scr, f_scr, cnt_scr, acc_scr) = refs
     else:
@@ -232,6 +230,7 @@ def _paged_prefill_kernel(
     i = pl.program_id(1)
     j = pl.program_id(2)
     b = bh // n_heads
+    kv_head = (bh % n_heads) // group
     start = start_ref[b]
     kv_len = kv_len_ref[b]
 
@@ -250,27 +249,31 @@ def _paged_prefill_kernel(
 
     @pl.when(live)
     def _step():
-        k = k_ref[0, :, 0, :]
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0]
+        v = v_ref[0]
         if quantized:
-            k = dequant_block(k, ks_ref[0, 0], kh_ref[0], deq_dtype)
-            v = dequant_block(v, vs_ref[0, 0], vh_ref[0], deq_dtype)
+            k = dequant_block(
+                k, head_scale(ks_ref[0], kv_head), kh_ref[0], deq_dtype, num
+            )
+            v = dequant_block(
+                v, head_scale(vs_ref[0], kv_head), vh_ref[0], deq_dtype, num
+            )
         _chunk_block_update(
             q_ref[0], k, v,
             start + i * block_q, j * page_size, kv_len,
             block_q, page_size,
             m_scr, l_scr, f_scr, cnt_scr, acc_scr,
-            inva=inva, beta=beta, stat_dtype=stat_dtype,
-            acc_dtype=acc_dtype, score_dtype=score_dtype,
+            inva=inva, beta=beta, in_dtype=deq_dtype, stat_dtype=stat_dtype,
+            acc_dtype=acc_dtype, score_dtype=score_dtype, num=num,
         )
 
     @pl.when(j == n_pages - 1)
     def _fin():
-        l = l_scr[:, :1].astype(acc_dtype)
+        l = num.fit(l_scr[:, :1], acc_dtype)
         # Rows past the real chunk never fold a block (l == 0); emit 0
         # instead of 0/0 so pad rows cannot NaN-poison downstream layers.
-        safe = jnp.where(l > 0.0, l, jnp.asarray(1.0, acc_dtype))
-        o_ref[0] = (acc_scr[...] / safe).astype(o_ref.dtype)
+        safe = jnp.where(l > 0.0, l, jnp.asarray(1.0, l.dtype))
+        o_ref[0] = num.fit(acc_scr[...] / safe, out_dtype)
 
 
 @functools.partial(
@@ -312,64 +315,58 @@ def paged_prefill_kernel_call(
     n_q = cs // block_q
     n_pages = page_table.shape[1]
     quantized = k_scale is not None
+    num = KernelNumerics(emulate_f16=not interpret)
 
-    qr = q.reshape(b * h, cs, d)
+    qr = num.to_storage(q.reshape(b * h, cs, d))
 
     kernel = functools.partial(
         _paged_prefill_kernel,
-        inva=inva, beta=beta, n_heads=h, block_q=block_q,
+        inva=inva, beta=beta, n_heads=h, group=group, block_q=block_q,
         page_size=page_size, n_pages=n_pages,
         stat_dtype=stat_dtype, acc_dtype=acc_dtype, score_dtype=score_dtype,
-        quantized=quantized, deq_dtype=deq_dtype,
+        out_dtype=out_dtype, quantized=quantized, deq_dtype=deq_dtype,
+        num=num,
     )
 
     def q_map(bh, i, j, st, kvl, pt):
         return (bh, i, 0)
 
-    def kv_map(bh, i, j, st, kvl, pt):
+    def page_map(bh, i, j, st, kvl, pt):
         # page gather: physical id from the prefetched table, before DMA
-        return (pt[bh // h, j], 0, (bh % h) // group, 0)
+        return (pt[bh // h, j], (bh % h) // group)
 
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), q_map),
-        pl.BlockSpec((1, page_size, 1, d), kv_map),
-        pl.BlockSpec((1, page_size, 1, d), kv_map),
-    ]
-    inputs = [qr, k_pages, v_pages]
-    if quantized:
-        def sc_map(bh, i, j, st, kvl, pt):
-            return (pt[bh // h, j], (bh % h) // group)
+    quant = (
+        dict(k_scale=k_scale, k_shift=k_shift, v_scale=v_scale,
+             v_shift=v_shift) if quantized else None
+    )
+    kv_specs, kv_inputs = pool_blocks(
+        num.to_storage(k_pages), num.to_storage(v_pages), quant, page_map
+    )
+    in_specs = [pl.BlockSpec((1, block_q, d), q_map)] + kv_specs
+    inputs = [qr] + kv_inputs
 
-        def sh_map(bh, i, j, st, kvl, pt):
-            return (pt[bh // h, j], (bh % h) // group, 0)
-
-        in_specs += [
-            pl.BlockSpec((1, 1), sc_map),
-            pl.BlockSpec((1, 1, d), sh_map),
-            pl.BlockSpec((1, 1), sc_map),
-            pl.BlockSpec((1, 1, d), sh_map),
-        ]
-        inputs += [k_scale, k_shift, v_scale, v_shift]
-
+    stat = num.storage(stat_dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b * h, n_q, n_pages),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_q, d), q_map),
         scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), stat_dtype),   # m
-            pltpu.VMEM((block_q, _LANES), stat_dtype),   # l
-            pltpu.VMEM((block_q, _LANES), stat_dtype),   # f
-            pltpu.VMEM((block_q, _LANES), stat_dtype),   # per-row block count
-            pltpu.VMEM((block_q, d), acc_dtype),         # accumulator
+            pltpu.VMEM((block_q, _LANES), stat),   # m
+            pltpu.VMEM((block_q, _LANES), stat),   # l
+            pltpu.VMEM((block_q, _LANES), stat),   # f
+            pltpu.VMEM((block_q, _LANES), stat),   # per-row block count
+            pltpu.VMEM((block_q, d), num.storage(acc_dtype)),  # accumulator
         ],
     )
 
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * h, cs, d), out_dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct(
+            (b * h, cs, d), num.storage(out_dtype)
+        ),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -378,7 +375,7 @@ def paged_prefill_kernel_call(
         page_table.astype(jnp.int32),
         *inputs,
     )
-    return out.reshape(b, h, cs, d)
+    return out.reshape(b, h, cs, d).astype(out_dtype)
 
 
 def paged_prefill_xla(

@@ -17,15 +17,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels.fp16 import KernelNumerics
 
 
-def _shift_kernel(m_ref, k_ref, o_ref, *, out_dtype):
+def _shift_kernel(m_ref, k_ref, o_ref, *, out_dtype, num: KernelNumerics):
     k = k_ref[0]                      # (bkv, d)
     m = m_ref[...]                    # (bkv, bkv)
-    o_ref[0] = jax.lax.dot_general(
-        m, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    ).astype(out_dtype)
+    o_ref[0] = num.fit(jax.lax.dot_general(
+        m, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=num.precision,
+    ), out_dtype)
 
 
 @functools.partial(
@@ -43,20 +44,21 @@ def shift_kv_kernel_call(
     if s2 % block_kv:
         raise ValueError(f"S2={s2} not divisible by block_kv={block_kv}")
     n_kv = s2 // block_kv
-    kr = k.reshape(b * kvh, s2, d)
+    num = KernelNumerics(emulate_f16=not interpret)
+    kr = num.to_storage(k.reshape(b * kvh, s2, d))
 
     out = pl.pallas_call(
-        functools.partial(_shift_kernel, out_dtype=out_dtype),
+        functools.partial(_shift_kernel, out_dtype=out_dtype, num=num),
         grid=(b * kvh, n_kv),
         in_specs=[
             pl.BlockSpec((block_kv, block_kv), lambda bh, j: (0, 0)),
             pl.BlockSpec((1, block_kv, d), lambda bh, j: (bh, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_kv, d), lambda bh, j: (bh, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * kvh, s2, d), out_dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((b * kvh, s2, d), num.storage(out_dtype)),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
-    )(m, kr)
-    return out.reshape(b, kvh, s2, d)
+    )(num.to_storage(m), kr)
+    return out.reshape(b, kvh, s2, d).astype(out_dtype)

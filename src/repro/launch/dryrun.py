@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this driver builds the production mesh (16x16 single-pod or
@@ -20,6 +17,7 @@ Usage:
 
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -158,9 +156,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
 
         mem = compiled.memory_analysis()
         print(f"[dryrun] {cid}: memory_analysis: {mem}")
-        from repro.compat import cost_analysis
 
-        ca = cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         raw_flops = float(ca.get("flops", 0.0))
         raw_bytes = float(ca.get("bytes accessed", 0.0))
         print(
@@ -239,6 +236,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
 
 
 def main():
+    # 512 placeholder host devices; must precede the first jax backend use
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -4,22 +4,26 @@ A FUNCTION (not a module-level constant) so importing this module never
 touches jax device state - the dry-run sets XLA_FLAGS *before* any jax
 device initialization and only then calls make_production_mesh().
 
-Mesh construction goes through :mod:`repro.compat` so ``axis_types`` (absent
-on older jax) is requested only where the installed jax supports it.
+Meshes are built with Auto axis types: sharding is left to GSPMD and the
+explicit ``shard_map`` regions, as every caller here expects.
 """
 
 from __future__ import annotations
 
-from repro import compat
+import jax
+
+
+def make_mesh(shape, axes):
+    """Arbitrary mesh (tests, elastic re-mesh)."""
+    axes = tuple(axes)
+    return jax.make_mesh(
+        tuple(shape), axes,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh (tests, elastic re-mesh)."""
-    return compat.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)

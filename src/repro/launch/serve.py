@@ -137,7 +137,7 @@ def parse_tenant_quotas(spec):
     return quotas
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--batch", type=int, default=4)
@@ -293,30 +293,67 @@ def main(argv=None):
                          "score-amplitude vs the fp16 ceiling, per-page "
                          "PASA shift magnitude, and K resonance on live "
                          "pages, read only at retirement drain points")
-    args = ap.parse_args(argv)
+    return ap
 
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
+
+def serving_config(args):
+    """The model config a serve runs: ``--arch`` (``--reduced``) with
+    bf16 weights - at published widths f32 weights do not fit one chip
+    (qwen3-4b: ~17.6 GB f32 against a 16 GB TPU v5e) - and the requested
+    KV page scale statistic."""
+    import dataclasses
 
     from repro.configs import get_config
-    from repro.launch.mesh import make_mesh, make_production_mesh
-    from repro.launch.sharding import set_mesh
-    from repro.launch.steps import make_serve_step
-    from repro.models.model_zoo import build
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    cfg = dataclasses.replace(cfg, param_dtype="bfloat16")
     if args.kv_quant_scale != "absmax":
-        import dataclasses
-
         cfg = dataclasses.replace(
             cfg,
             attention=dataclasses.replace(
                 cfg.attention, kv_quant_scale=args.kv_quant_scale
             ),
         )
+    return cfg
+
+
+def init_params(bundle, seed: int, sharding=None):
+    """Seeded random weights, placed at ``sharding`` (default: the default
+    device).  One jitted program: each leaf's f32 draw fuses with its
+    cast, so no f32 copy of a leaf is ever held beside the bf16 params."""
+    import jax
+
+    placed = {} if sharding is None else {"out_shardings": sharding}
+    return jax.jit(bundle.init, **placed)(jax.random.PRNGKey(seed))
+
+
+def make_prompts(cfg, args):
+    import numpy as np
+
+    rng = np.random.default_rng(args.seed)
+    return rng.integers(
+        0, cfg.vocab_size, (args.batch, args.prompt_len), dtype=np.int32
+    )
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import make_mesh, make_production_mesh
+    from repro.launch.sharding import set_mesh
+    from repro.launch.steps import make_serve_step
+    from repro.models.model_zoo import build
+
+    enable_compile_cache()
+    cfg = serving_config(args)
     if args.mesh == "prod":
         mesh = make_production_mesh()
     else:
@@ -326,12 +363,12 @@ def main(argv=None):
     max_len = args.max_len or (args.prompt_len + args.gen + 8)
 
     bundle = build(cfg)
+    # a multi-device serve replicates the weights on every device: create
+    # them there, not on device 0 and then copied
+    replicated = NamedSharding(mesh, PartitionSpec()) if mesh.size > 1 else None
     with mesh:
-        params = bundle.init(jax.random.PRNGKey(args.seed))
-        rng = np.random.default_rng(args.seed)
-        prompts = rng.integers(
-            0, cfg.vocab_size, (args.batch, args.prompt_len), dtype=np.int32
-        )
+        params = init_params(bundle, args.seed, replicated)
+        prompts = make_prompts(cfg, args)
 
         if args.paged:
             return _serve_paged(args, bundle, params, prompts, mesh)
@@ -386,20 +423,21 @@ def main(argv=None):
         return gen
 
 
-def _serve_paged(args, bundle, params, prompts, mesh=None):
-    """Serve the same workload through the paged-KV engine.
+def make_paged_engine(args, bundle, params, mesh=None, **engine_kwargs):
+    """Build the paged engine the CLI serves through.
 
     The mesh is USED here (not just activated): with ``--mesh DxM``,
     the ``data`` axis becomes D engine replicas fed round-robin from one
     queue (:class:`repro.runtime.EngineReplicaGroup`) and the ``model``
     axis shards each replica's page pool kv-head-split across its M
     devices (``ServeEngine(mesh=...)``) - both bit-preserving, so the
-    DxM serve's streams match the 1x1 serve token for token."""
+    DxM serve's streams match the 1x1 serve token for token.  Extra
+    ``engine_kwargs`` (telemetry, on_token) pass through.
+
+    Returns ``(engine, placement, num_pages, page_size)``."""
     import math
 
-    import numpy as np
-
-    from repro.runtime import EngineReplicaGroup, ServeEngine, Telemetry
+    from repro.runtime import EngineReplicaGroup, ServeEngine
 
     page_size = (
         args.page_size if args.page_size is not None
@@ -430,7 +468,7 @@ def _serve_paged(args, bundle, params, prompts, mesh=None):
             parse_tenant_quotas(args.tenant_quotas),
             patience=max(args.preempt_patience, 1),
         )
-    engine_kwargs = dict(
+    engine_kwargs.update(
         max_batch=batch_per, num_pages=num_pages, page_size=page_size,
         max_seq_len=total,
         chunked_prefill=args.chunked_prefill,
@@ -449,6 +487,25 @@ def _serve_paged(args, bundle, params, prompts, mesh=None):
         speculate=args.speculate,
         draft=args.draft,
     )
+    if mesh is not None and (n_data > 1 or n_model > 1):
+        eng = EngineReplicaGroup(
+            bundle, params, mesh, routing=args.routing, **engine_kwargs
+        )
+        placement = f"{n_data} replicas x model={n_model} pool shards"
+    else:
+        eng = ServeEngine(bundle, params, **engine_kwargs)
+        placement = "1 device"
+    return eng, placement, num_pages, page_size
+
+
+def _serve_paged(args, bundle, params, prompts, mesh=None):
+    """Serve the same workload through the paged-KV engine
+    (:func:`make_paged_engine`)."""
+    import numpy as np
+
+    from repro.runtime import Telemetry
+
+    engine_kwargs = {}
 
     # observability: one Telemetry per serve, layers switched by flags.
     # Bit-neutral - every hook reads host state only; the numerics probe
@@ -478,14 +535,9 @@ def _serve_paged(args, bundle, params, prompts, mesh=None):
                 hangup.append(0)
         engine_kwargs["on_token"] = on_token
 
-    if mesh is not None and (n_data > 1 or n_model > 1):
-        eng = EngineReplicaGroup(
-            bundle, params, mesh, routing=args.routing, **engine_kwargs
-        )
-        placement = f"{n_data} replicas x model={n_model} pool shards"
-    else:
-        eng = ServeEngine(bundle, params, **engine_kwargs)
-        placement = "1 device"
+    eng, placement, num_pages, page_size = make_paged_engine(
+        args, bundle, params, mesh, **engine_kwargs
+    )
     reqs = [eng.submit(list(p), args.gen) for p in prompts]
     t0 = time.time()
     if args.stream or args.disconnect_after:

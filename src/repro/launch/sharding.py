@@ -38,16 +38,21 @@ def get_mesh() -> Optional[Mesh]:
     return getattr(_state, "mesh", None)
 
 
+def manual_axes() -> frozenset:
+    """Mesh axes that are manual (shard_map-bound) at this trace point."""
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
+        return frozenset()
+    manual = jax.sharding.AxisType.Manual
+    return frozenset(
+        n for n, t in zip(am.axis_names, am.axis_types) if t == manual
+    )
+
+
 def in_manual_region() -> bool:
     """True when tracing inside a shard_map with manual axes - nested manual
     shard_maps over a different axis set are rejected by JAX, so callers
-    (row-parallel matmul, a2a MoE) fall back to their GSPMD paths there.
-
-    Delegates to :func:`repro.compat.manual_axes`, which reads the abstract
-    mesh on modern jax and falls back to compat.shard_map's thread-local
-    tracking on older jax (no ``get_abstract_mesh``)."""
-    from repro.compat import manual_axes
-
+    (row-parallel matmul, a2a MoE) fall back to their GSPMD paths there."""
     return bool(manual_axes())
 
 
@@ -70,8 +75,6 @@ def shard(x, *spec):
     mesh = get_mesh()
     if mesh is None:
         return x
-    from repro.compat import manual_axes
-
     names = set(mesh.axis_names)
     # axes already manual (inside an enclosing shard_map) can't appear in
     # with_sharding_constraint specs; auto axes still accept constraints

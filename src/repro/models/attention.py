@@ -294,9 +294,10 @@ def attention(
         # page_table[b, pos // page] at slot pos % page; inactive batch
         # slots carry page_table rows of null pages (page 0), so their
         # writes land in the reserved sink and the pool stays consistent.
-        # The read is the XLA gather fallback (jnp.take of each sequence's
-        # pages); on a TPU runtime the fused kernels/pasa_paged_decode.py
-        # path replaces gather+attend with page-table scalar prefetch.
+        # The read is the XLA gather (jnp.take of each sequence's pages)
+        # on every platform: the served path calls no Pallas kernel
+        # (kernels/pasa_paged_decode.py computes the same attention and is
+        # validated against this gather path, but nothing routes here).
         from repro.runtime.paged_cache import (
             dequantize_kv_page,
             gather_pages,

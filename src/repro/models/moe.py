@@ -256,9 +256,7 @@ def moe_ffn_a2a(x: jnp.ndarray, p, cfg: ModelConfig, mesh) -> jnp.ndarray:
         out = jax.lax.all_gather(out_mine, "model", axis=0, tiled=True)
         return out.reshape(b_loc, s, d)
 
-    from repro.compat import shard_map as _shard_map
-
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

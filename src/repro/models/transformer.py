@@ -163,7 +163,7 @@ def serve_step(params, cfg: ModelConfig, token: jnp.ndarray, pos: jnp.ndarray,
     x = L.embed(token[:, None], params["embed"], cd)  # (B, 1, D)
     x, new_cache = _scan_blocks(x, params["blocks"], cfg, cache=cache, pos=pos)
     h = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = h[:, 0].astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
+    logits = L.lm_logits(h[:, 0], params["lm_head"])
     logits = shard(logits, dp_axes(), "model")
     return logits, new_cache
 
@@ -186,7 +186,7 @@ def serve_step_paged(
         x, params["blocks"], cfg, cache=cache, pos=pos, page_table=page_table,
     )
     h = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = h[:, 0].astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
+    logits = L.lm_logits(h[:, 0], params["lm_head"])
     logits = shard(logits, dp_axes(), "model")
     return logits, new_cache
 
@@ -202,9 +202,7 @@ def prefill_logits(params, cfg: ModelConfig, tokens: jnp.ndarray, cache: dict):
     argmax of the returned logits is the first generated token and decode
     continues at pos == S (launch/serve.py dense route)."""
     h, new_cache = forward(params, cfg, tokens, cache=cache, prefill_cache=True)
-    logits = (
-        h[:, -1].astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
-    )
+    logits = L.lm_logits(h[:, -1], params["lm_head"])
     logits = shard(logits, dp_axes(), "model")
     return logits, new_cache
 
@@ -245,6 +243,6 @@ def prefill_step_paged(
     hl = jnp.take_along_axis(
         h, last_idx.astype(jnp.int32)[:, None, None], axis=1
     )[:, 0]                                            # (B, D)
-    logits = hl.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
+    logits = L.lm_logits(hl, params["lm_head"])
     logits = shard(logits, dp_axes(), "model")
     return logits, new_cache

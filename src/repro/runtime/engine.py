@@ -696,12 +696,14 @@ class ServeEngine:
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            from repro.compat import shard_map as _shard_map
             from repro.runtime.paged_cache import pool_pspecs
 
             repl = NamedSharding(mesh, P())
             pshard = pool_shardings(mesh, self.pool, bundle.cfg.n_kv_heads)
             prepl = jax.tree.map(lambda _: repl, self.params)
+            # place the replicated weights once (a no-op when the caller
+            # created them there), not on every step's jit boundary
+            self.params = jax.device_put(self.params, prepl)
             extra = (repl, repl) if sampled else ()
             step_jit = dict(
                 in_shardings=(prepl, repl, repl, pshard, repl) + extra,
@@ -718,7 +720,7 @@ class ServeEngine:
             pr_spec = jax.tree.map(lambda _: rp, self.params)
             extra_sp = (rp, rp) if sampled else ()
             wrap = self._make_pool_io(mesh, pspec)
-            _device_step = _shard_map(
+            _device_step = jax.shard_map(
                 wrap(_device_step, 3), mesh=mesh,
                 in_specs=(pr_spec, rp, rp, pspec, rp) + extra_sp,
                 out_specs=(rp, pspec), check_vma=False,
@@ -748,7 +750,7 @@ class ServeEngine:
                     return nxt, new_pool
 
             if mesh is not None:
-                _device_prefill = _shard_map(
+                _device_prefill = jax.shard_map(
                     wrap(_device_prefill, 5), mesh=mesh,
                     in_specs=(
                         (pr_spec, rp, rp, rp, rp, pspec, rp) + extra_sp
@@ -830,7 +832,7 @@ class ServeEngine:
                     ),
                     out_shardings=((repl, repl, repl), pshard),
                 )
-                _device_verify = _shard_map(
+                _device_verify = jax.shard_map(
                     wrap(_device_verify, 4), mesh=mesh,
                     in_specs=(pr_spec, rp, rp, rp, pspec, rp) + extra_sp,
                     out_specs=((rp, rp, rp), pspec), check_vma=False,

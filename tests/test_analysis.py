@@ -253,7 +253,7 @@ def test_tenant_rule_flags_labels_in_jitted_functions():
 def test_tenant_rule_traces_shard_map_wrapping():
     src = """\
         import jax
-        from repro.compat import shard_map as _shard_map
+        _shard_map = jax.shard_map
 
         def _device_step(params, token, pool, req_id_vec):
             return pool[req_id_vec]

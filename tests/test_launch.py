@@ -39,11 +39,9 @@ def test_cost_analysis_undercounts_scans():
             return c @ w, None
         return jax.lax.scan(body, x, None, length=10)[0]
 
-    from repro.compat import cost_analysis
-
     sds = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     comp = jax.jit(f).lower(sds, sds).compile()
-    raw = cost_analysis(comp)["flops"]
+    raw = comp.cost_analysis()["flops"]
     fixed = analyze(comp.as_text())["dot_flops"]
     expected = 10 * 2 * 128**3
     assert raw == pytest.approx(expected / 10, rel=0.01)
@@ -65,12 +63,10 @@ def test_hlo_analysis_nested_scans():
 
 
 def test_hlo_analysis_collectives_in_loops(mesh4):
-    from repro.compat import shard_map
-
     def f(x):
         def body(c, _):
             return jax.lax.psum(c, "model"), None
-        g = shard_map(
+        g = jax.shard_map(
             lambda c: jax.lax.scan(body, c, None, length=7)[0],
             mesh=mesh4, in_specs=P("model"), out_specs=P("model"),
             check_vma=False,
@@ -268,18 +264,6 @@ def test_compressed_train_step_cross_pod():
     within quantization error, and the wire is int16 in the HLO."""
     if jax.device_count() < 8:
         pytest.skip("needs 8 devices")
-    if not hasattr(jax, "shard_map"):
-        # Upstream XLA bug in the jaxlib bundled with legacy-shard_map jax
-        # (<= 0.4.x): partitioning a while-loop (scan-under-grad) inside a
-        # partial-auto (manual-subgroup) shard_map hits
-        # `Check failed: sharding.IsManualSubgroup()` in
-        # xla/hlo/utils/hlo_sharding_util.cc and aborts the process.
-        # Minimal repro: grad(scan(matmul)) under shard_map(auto={...}).
-        # The compressed step itself is exercised on modern jax runtimes.
-        pytest.skip(
-            "partial-auto shard_map + scan-under-grad aborts XLA on "
-            "legacy jax (hlo_sharding_util IsManualSubgroup check)"
-        )
     from repro.configs import get_config
     from repro.launch.steps import (
         TrainHyper, init_train_state, make_compressed_train_step,
@@ -319,6 +303,11 @@ def test_compressed_train_step_cross_pod():
                         jax.tree.leaves(sp["params"]))
     )
     assert d < 1e-3  # int8 quantization error with error feedback
+    # data-parallel within each pod: the batch splits over pod x data
+    tok_sharding = compiled.input_shardings[0][1]["tokens"]
+    assert tok_sharding.is_equivalent_to(
+        NamedSharding(mesh, P(("pod", "data"))), 2
+    )
     n_s16 = sum(
         1 for ln in compiled.as_text().splitlines()
         if "all-reduce" in ln and "s16[" in ln

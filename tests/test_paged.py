@@ -155,6 +155,79 @@ def test_paged_kernel_vs_xla_fallback_and_gold(kv_lens, rng):
         assert rmse(xla[bi : bi + 1], gold) < 0.03
 
 
+@pytest.mark.parametrize("quantized", [False, True])
+def test_emulated_fp16_kernels_vs_xla_fallback(quantized, monkeypatch, rng):
+    """Compiled (Mosaic) kernels hold fp16 values in f32 containers and
+    round at the policy's storage points (kernels/fp16.py); interpret mode
+    keeps native fp16.  Run the emulated bodies in interpret mode: paged
+    decode and paged prefill stay within the kernel-vs-fallback tolerance
+    of the native kernels."""
+    import importlib
+
+    from repro.kernels import fp16
+    from repro.kernels.ops import _check_quant
+
+    # the package re-exports functions under these module names
+    pasa_paged_decode = importlib.import_module(
+        "repro.kernels.pasa_paged_decode"
+    )
+    pasa_paged_prefill = importlib.import_module(
+        "repro.kernels.pasa_paged_prefill"
+    )
+    from repro.runtime.paged_cache import quantize_kv_page
+
+    b, kvh, g, d, page = 2, 2, 4, 64, 128
+    q, kc, vc, kv_len, kp, vp, table = _paged_setup(
+        rng, b, kvh, g, d, [300, 77], page
+    )
+    quant = {}
+    if quantized:
+        valid = jnp.ones(kp.shape[:2], bool)
+        kp, ksc, ksh = quantize_kv_page(kp, valid, jnp.int8)
+        vp, vsc, vsh = quantize_kv_page(vp, valid, jnp.int8)
+        quant = _check_quant(kp, (ksc, ksh, vsc, vsh))
+    else:
+        kp, vp = kp.astype(jnp.float16), vp.astype(jnp.float16)
+    emulated = lambda emulate_f16: fp16.KernelNumerics(emulate_f16=True)
+    for mod in (pasa_paged_decode, pasa_paged_prefill):
+        monkeypatch.setattr(mod, "KernelNumerics", emulated)
+    pol = dict(
+        stat_dtype=FP16.stat_dtype, acc_dtype=FP16.acc_dtype,
+        score_dtype=FP16.score_dtype, out_dtype=FP16.out_dtype,
+        deq_dtype=FP16.input_dtype, interpret=True,
+    )
+    inva = BETA / (1.0 - BETA)
+
+    got = pasa_paged_decode.paged_decode_kernel_call.__wrapped__(
+        q.astype(jnp.float16), kp, vp, table, kv_len,
+        inva=inva, beta=BETA, **pol, **quant,
+    )
+    want = K.pasa_paged_decode(
+        q, kp, vp, table, kv_len, beta=BETA, policy=FP16, use_kernel=False,
+        **quant,
+    )
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=3e-3, rtol=3e-2,
+    )
+
+    cs = 64
+    qp = jax.random.normal(rng, (b, kvh * g, cs, d), jnp.float32)
+    start = kv_len - cs
+    got = pasa_paged_prefill.paged_prefill_kernel_call.__wrapped__(
+        qp.astype(jnp.float16), kp, vp, table, start, kv_len,
+        inva=inva, beta=BETA, block_q=cs, **pol, **quant,
+    )
+    want = K.pasa_paged_prefill(
+        qp, kp, vp, table, start, kv_len, beta=BETA, policy=FP16,
+        use_kernel=False, **quant,
+    )
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=3e-3, rtol=3e-2,
+    )
+
+
 def test_paged_xla_fallback_bitmatches_dense_xla(rng):
     """The gather fallback == blocked_attention on the contiguous cache,
     bit-for-bit, even though the paged view is longer (its trailing dead

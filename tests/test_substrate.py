@@ -96,7 +96,7 @@ def test_compressed_psum_error_feedback():
     quantization error, residual carries the rest."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((1,), ("pod",))
 
@@ -111,7 +111,7 @@ def test_compressed_psum_error_feedback():
         )
         return out, new.residual
 
-    fm = shard_map(
+    fm = jax.shard_map(
         f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
         check_vma=False,
     )

@@ -128,3 +128,43 @@ def test_serve_generates_self_consistently():
 
     assert gen(5) == gen(5)
     assert 0 <= min(gen(5)) and max(gen(5)) < cfg.vocab_size
+
+
+def test_compile_cache_goes_where_placed(tmp_path):
+    """launch/compile_cache: with JAX_COMPILATION_CACHE_DIR set, compiled
+    programs land there and the checkout's default directory is not
+    configured; without it, the one fixed in-checkout directory is.  Run
+    in child interpreters (CPU) so this process's JAX config is untouched."""
+    import subprocess
+    import sys
+
+    from repro.launch.compile_cache import DEFAULT_DIR
+
+    body = (
+        "import jax\n"
+        "from repro.launch.compile_cache import enable_compile_cache\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+        "d = enable_compile_cache()\n"
+        "if {compile}:\n"
+        "    jax.jit(lambda x: x * 3 + 1)(jax.numpy.ones(5)).block_until_ready()\n"
+        "print(d, jax.config.jax_compilation_cache_dir)\n"
+    )
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    base = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src)
+    base.pop("JAX_COMPILATION_CACHE_DIR", None)
+
+    outside = tmp_path / "cache"
+    out = subprocess.run(
+        [sys.executable, "-c", body.format(compile=True)],
+        env=dict(base, JAX_COMPILATION_CACHE_DIR=str(outside)),
+        capture_output=True, text=True, timeout=300, check=True,
+    ).stdout.split()
+    assert out == [str(outside), str(outside)]
+    assert any(outside.iterdir())
+
+    out = subprocess.run(
+        [sys.executable, "-c", body.format(compile=False)],
+        env=base, capture_output=True, text=True, timeout=300, check=True,
+    ).stdout.split()
+    assert out == [str(DEFAULT_DIR), str(DEFAULT_DIR)]
+    assert DEFAULT_DIR.name == ".jax_cache"
