@@ -8,16 +8,13 @@
 #     mean/worst TTFT and drain steps per policy x prefill-batch
 #     configuration, plus the wall-clock sync-vs-async pipelining pair
 #     (real tokens/sec and TTFT-seconds; streams asserted bit-identical).
+# The cost of telemetry is measured on the chip (PERF.md), not here.
 import json
 import os
 import sys
 
 NUMERICS_JSON = os.path.join(os.path.dirname(__file__), "BENCH_numerics.json")
 SERVING_JSON = os.path.join(os.path.dirname(__file__), "BENCH_serving.json")
-
-#: PR-7 acceptance bound: full telemetry (wallclock_traced) may cost at
-#: most this fraction of wallclock_async tokens/sec.
-TELEMETRY_OVERHEAD_BOUND = 0.05
 
 #: PR-9 acceptance bound: the speculative row of the repetitive burst
 #: must finish at or below this many engine steps per generated token
@@ -53,32 +50,6 @@ def _check_spec_decode(serving_rows) -> None:
     print(
         f"[spec decode {spt:.3f} steps/token, bit-identical - within the "
         f"{SPEC_STEPS_PER_TOKEN_BOUND} bound]", file=sys.stderr,
-    )
-
-
-def _check_telemetry_overhead(serving_rows) -> None:
-    """Fail the whole run when observability costs more than the bound; a
-    silent perf regression in a ride-along layer must not survive a green
-    run."""
-    traced = next(
-        (r for r in serving_rows
-         if r["name"] == "scheduler_burst/wallclock_traced"), None,
-    )
-    if traced is None:
-        raise SystemExit(
-            "wallclock_traced row missing from the serving trajectory - "
-            "the telemetry-overhead acceptance bound was not measured"
-        )
-    overhead = traced["overhead_vs_async"]
-    if overhead > TELEMETRY_OVERHEAD_BOUND:
-        raise SystemExit(
-            f"telemetry overhead {overhead:.1%} exceeds the "
-            f"{TELEMETRY_OVERHEAD_BOUND:.0%} bound vs wallclock_async "
-            f"({traced['tokens_per_s_wall']:.0f} tok/s traced)"
-        )
-    print(
-        f"[telemetry overhead {overhead:+.1%} vs async - within the "
-        f"{TELEMETRY_OVERHEAD_BOUND:.0%} bound]", file=sys.stderr,
     )
 
 
@@ -121,7 +92,6 @@ def main() -> None:
     rows += SB.report()
     serving_rows = SB.serving_rows()
     _write_json(SERVING_JSON, serving_rows, "serving")
-    _check_telemetry_overhead(serving_rows)
     _check_spec_decode(serving_rows)
     # empty when no dry-run artifacts exist (experiments/dryrun)
     rows += R.report()

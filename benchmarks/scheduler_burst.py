@@ -36,12 +36,6 @@ complement (never replace) the deterministic step-count rows: steps are
 the diffable cross-PR contract, wall-clock is the honest-throughput
 claim ROADMAP flagged as missing.
 
-The ``wallclock_traced`` row (PR 7) repeats the async run with FULL
-telemetry attached (step tracing + metrics + the numerics probe at its
-production cadence): streams asserted bit-identical, and the recorded
-``overhead_vs_async`` is the price of observability - bounded at 5% by
-benchmarks/run.py, loudly.
-
 The speculative-decode rows (PR 9): ``scheduler_burst/spec_decode_off``
 vs ``spec_decode_on`` serve a repetitive burst (constant-token prompts,
 near-cyclic greedy continuations) plainly and with K=6 n-gram
@@ -94,7 +88,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.models.model_zoo import build
 from repro.runtime import (
-    ServeEngine, Telemetry, TenantQuota, TenantQuotaPolicy,
+    ServeEngine, TenantQuota, TenantQuotaPolicy,
 )
 
 PROMPTS = (96, 32, 96, 64, 32, 64)   # staggered burst, mixed lengths
@@ -168,17 +162,10 @@ CONFIGS = (
 WALL_PROMPTS = (24, 16, 32, 16, 24, 16, 32, 24) * 2
 WALL_GEN = 32
 WALL_REPS = 6          # even: the alternating pair order stays balanced
-# Production probe cadence for the traced row.  Each numerics sample
-# forces a device sync (its readback drains the in-flight pipelined
-# step), so the cadence - not the per-sample host math - sets the probe's
-# wall cost; 128 steps keeps the monitor live on a multi-thousand-step
-# serve while amortizing the sync below the noise floor.
-TRACED_PROBE_EVERY = 128
 
 
 def wallclock_metrics(reps: int = WALL_REPS):
-    """Real-time sync / async / traced comparison on the decode-heavy
-    burst.
+    """Real-time sync / async comparison on the decode-heavy burst.
 
     Method: per mode, warm BOTH jitted calls with a throwaway request,
     then serve the staggered burst ``reps`` times; the timed region syncs
@@ -188,13 +175,7 @@ def wallclock_metrics(reps: int = WALL_REPS):
     streaming callback - the latency a streaming client actually sees,
     including the async mode's one-step emission lag.  Streams are
     asserted bit-identical across modes (the overlap must not change the
-    schedule's outputs, only its wall-clock).
-
-    The ``traced`` mode is the async engine with FULL telemetry
-    (tracing + metrics + the numerics probe at its production cadence,
-    every ``TRACED_PROBE_EVERY`` steps) - the observability-cost row.
-    Its acceptance bound, enforced by benchmarks/run.py on the recorded
-    JSON: <= 5% wall tokens/sec below ``wallclock_async``."""
+    schedule's outputs, only its wall-clock)."""
     cfg, bundle, params = _bundle()
     rng = np.random.default_rng(1)
     prompts = [list(rng.integers(0, cfg.vocab_size, n)) for n in WALL_PROMPTS]
@@ -202,7 +183,7 @@ def wallclock_metrics(reps: int = WALL_REPS):
     num_pages = 1 + sum(
         math.ceil((len(p) + WALL_GEN) / PAGE) for p in prompts
     )
-    modes = (("sync", 0), ("async", 1), ("traced", 1))
+    modes = (("sync", 0), ("async", 1))
     rates = {m: [] for m, _ in modes}
     ttfts = {m: [] for m, _ in modes}
     streams: dict = {}
@@ -215,14 +196,10 @@ def wallclock_metrics(reps: int = WALL_REPS):
             if idx == 0 and r.req_id in clocks:   # warmup req has no clock
                 ttfts[mode].append(time.perf_counter() - clocks[r.req_id])
 
-        telemetry = Telemetry(
-            tracing=True, metrics=True,
-            numerics_every=TRACED_PROBE_EVERY,
-        ) if mode == "traced" else None
         eng = ServeEngine(
             bundle, params, max_batch=BATCH, num_pages=num_pages,
             page_size=PAGE, max_seq_len=total, prefill_chunk=CHUNK,
-            pipeline_depth=depth, on_token=on_token, telemetry=telemetry,
+            pipeline_depth=depth, on_token=on_token,
         )
         eng.submit(list(prompts[0][:2]), 2)
         eng.run_to_completion()                   # warm both jitted calls
@@ -266,20 +243,12 @@ def wallclock_metrics(reps: int = WALL_REPS):
         }
     assert streams["async"] == streams["sync"], \
         "async burst diverged from sync (bit-identity broken)"
-    assert streams["traced"] == streams["sync"], \
-        "traced burst diverged from sync (telemetry not bit-neutral)"
     # paired ratio per interleaved rep: adjacent runs share whatever the
     # host was doing that second, so the ratio is far more stable than
     # the quotient of two independently-noisy medians
     out["async"]["speedup_vs_sync"] = float(np.median(
         np.asarray(rates["async"]) / np.asarray(rates["sync"])
     ))
-    # the observability-cost headline: fractional tok/s lost to full
-    # telemetry, paired per rep against the uninstrumented async engine
-    out["traced"]["overhead_vs_async"] = float(1.0 - np.median(
-        np.asarray(rates["traced"]) / np.asarray(rates["async"])
-    ))
-    out["traced"]["numerics_every"] = TRACED_PROBE_EVERY
     return out
 
 
@@ -719,15 +688,10 @@ def report():
             f"{base / m['mean_ttft_steps']:.2f}x vs fcfs_b1",
         ))
     wall = _wall_metrics()
-    for mode in ("sync", "async", "traced"):
+    for mode in ("sync", "async"):
         m = wall[mode]
-        if mode == "async":
-            extra = f" | {m['speedup_vs_sync']:.2f}x vs sync"
-        elif mode == "traced":
-            extra = (f" | full telemetry, {m['overhead_vs_async'] * 100:+.1f}%"
-                     " overhead vs async")
-        else:
-            extra = ""
+        extra = (f" | {m['speedup_vs_sync']:.2f}x vs sync"
+                 if mode == "async" else "")
         rows.append((
             f"scheduler_burst_wallclock_{mode}", 0.0,
             f"{m['tokens_per_s_wall']:.0f} tok/s wall | "
@@ -810,7 +774,7 @@ def serving_rows():
             },
         })
     wall = _wall_metrics()
-    for mode in ("sync", "async", "traced"):
+    for mode in ("sync", "async"):
         m = wall[mode]
         row = {
             "name": f"scheduler_burst/wallclock_{mode}",
@@ -828,12 +792,6 @@ def serving_rows():
         }
         if mode == "async":
             row["speedup_vs_sync"] = m["speedup_vs_sync"]
-        if mode == "traced":
-            row["overhead_vs_async"] = m["overhead_vs_async"]
-            row["telemetry"] = {
-                "tracing": True, "metrics": True,
-                "numerics_every": m["numerics_every"],
-            }
         out.append(row)
     sd = _spec_metrics()
     for mode in ("off", "on"):

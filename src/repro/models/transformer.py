@@ -37,23 +37,46 @@ def init_block(key, cfg: ModelConfig, dtype, n_stack: int):
     return p
 
 
+#: The parts of a model step, each traced under its own
+#: ``jax.named_scope`` so the device trace names them in every op's
+#: ``op_name``: ``embed``; ``qkv`` (pre-attention norm, projections, q/k
+#: norm, rope); ``kv_write`` (the scatter of the new K/V into the pool,
+#: requantization of a quantized pool's tail page); ``kv_gather`` (the
+#: read of each row's pages and its cast to the compute dtype); ``pasa``
+#: (attention proper); ``attn_out`` (output projection, residual add);
+#: ``mlp`` (post-attention norm, MLP or MoE, residual add); ``head``
+#: (final norm, logits); ``sample`` (argmax or the sampler, in
+#: runtime/engine.py); ``layer_scan`` (the loop over the stacked layers
+#: itself: each layer's weights and pool sliced out of the stacks, its
+#: pool written back, loop control - the loop's ops that belong to no
+#: part above).  Scopes only name ops: they change no fusion and no value.
+STEP_SCOPES = (
+    "embed", "qkv", "kv_write", "kv_gather", "pasa", "attn_out", "mlp",
+    "head", "sample", "layer_scan",
+)
+
+
 def block_apply(
     x, p, cfg: ModelConfig, *, causal=True, cache=None, pos=None,
     prefill_cache=False, page_table=None, prefill_len=None,
 ):
     cd = cfg.jnp_compute_dtype()
+    with jax.named_scope("qkv"):
+        xn = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     h, new_cache = attn_mod.attention(
-        L.rms_norm(x, p["ln1"], cfg.norm_eps), p["attn"], cfg,
+        xn, p["attn"], cfg,
         causal=causal, cache=cache, pos=pos, prefill_cache=prefill_cache,
         page_table=page_table, prefill_len=prefill_len,
     )
-    x = x + h.astype(x.dtype)
-    ff_in = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-    if cfg.family == "moe":
-        ff = moe_mod.moe_ffn(ff_in, p["moe"], cfg)
-    else:
-        ff = L.mlp(ff_in, p["mlp"], cd)
-    x = x + ff.astype(x.dtype)
+    with jax.named_scope("attn_out"):
+        x = x + h.astype(x.dtype)
+    with jax.named_scope("mlp"):
+        ff_in = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+        if cfg.family == "moe":
+            ff = moe_mod.moe_ffn(ff_in, p["moe"], cfg)
+        else:
+            ff = L.mlp(ff_in, p["mlp"], cd)
+        x = x + ff.astype(x.dtype)
     x = shard(x, dp_axes(), None, None)
     return x, new_cache
 
@@ -96,7 +119,8 @@ def _scan_blocks(x, stacked, cfg, *, cache=None, pos=None, prefill_cache=False,
         return y, nc
 
     xs = stacked if cache is None else (stacked, cache)
-    x, new_caches = jax.lax.scan(body, x, xs)
+    with jax.named_scope("layer_scan"):
+        x, new_caches = jax.lax.scan(body, x, xs)
     return x, new_caches
 
 
@@ -106,12 +130,15 @@ def forward(
 ) -> Tuple[jnp.ndarray, Optional[dict]]:
     """tokens (B, S) -> final hidden states (B, S, D) (+ updated caches)."""
     cd = cfg.jnp_compute_dtype()
-    x = L.embed(tokens, params["embed"], cd)
+    with jax.named_scope("embed"):
+        x = L.embed(tokens, params["embed"], cd)
     x, new_caches = _scan_blocks(
         x, params["blocks"], cfg, cache=cache, pos=pos,
         prefill_cache=prefill_cache,
     )
-    return L.rms_norm(x, params["final_norm"], cfg.norm_eps), new_caches
+    with jax.named_scope("head"):
+        h = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return h, new_caches
 
 
 def loss_fn(params, cfg: ModelConfig, batch) -> jnp.ndarray:
@@ -160,11 +187,13 @@ def serve_step(params, cfg: ModelConfig, token: jnp.ndarray, pos: jnp.ndarray,
                cache: dict):
     """One decode step: token (B,), pos (B,) -> (logits (B, V), new cache)."""
     cd = cfg.jnp_compute_dtype()
-    x = L.embed(token[:, None], params["embed"], cd)  # (B, 1, D)
+    with jax.named_scope("embed"):
+        x = L.embed(token[:, None], params["embed"], cd)  # (B, 1, D)
     x, new_cache = _scan_blocks(x, params["blocks"], cfg, cache=cache, pos=pos)
-    h = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = L.lm_logits(h[:, 0], params["lm_head"])
-    logits = shard(logits, dp_axes(), "model")
+    with jax.named_scope("head"):
+        h = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = L.lm_logits(h[:, 0], params["lm_head"])
+        logits = shard(logits, dp_axes(), "model")
     return logits, new_cache
 
 
@@ -181,13 +210,15 @@ def serve_step_paged(
     bit-comparable between the two cache layouts.
     """
     cd = cfg.jnp_compute_dtype()
-    x = L.embed(token[:, None], params["embed"], cd)  # (B, 1, D)
+    with jax.named_scope("embed"):
+        x = L.embed(token[:, None], params["embed"], cd)  # (B, 1, D)
     x, new_cache = _scan_blocks(
         x, params["blocks"], cfg, cache=cache, pos=pos, page_table=page_table,
     )
-    h = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = L.lm_logits(h[:, 0], params["lm_head"])
-    logits = shard(logits, dp_axes(), "model")
+    with jax.named_scope("head"):
+        h = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = L.lm_logits(h[:, 0], params["lm_head"])
+        logits = shard(logits, dp_axes(), "model")
     return logits, new_cache
 
 
@@ -202,8 +233,9 @@ def prefill_logits(params, cfg: ModelConfig, tokens: jnp.ndarray, cache: dict):
     argmax of the returned logits is the first generated token and decode
     continues at pos == S (launch/serve.py dense route)."""
     h, new_cache = forward(params, cfg, tokens, cache=cache, prefill_cache=True)
-    logits = L.lm_logits(h[:, -1], params["lm_head"])
-    logits = shard(logits, dp_axes(), "model")
+    with jax.named_scope("head"):
+        logits = L.lm_logits(h[:, -1], params["lm_head"])
+        logits = shard(logits, dp_axes(), "model")
     return logits, new_cache
 
 
@@ -234,15 +266,17 @@ def prefill_step_paged(
     prefix-cache sharing contract.
     """
     cd = cfg.jnp_compute_dtype()
-    x = L.embed(tokens, params["embed"], cd)          # (B, CS, D)
+    with jax.named_scope("embed"):
+        x = L.embed(tokens, params["embed"], cd)      # (B, CS, D)
     x, new_cache = _scan_blocks(
         x, params["blocks"], cfg, cache=cache, pos=start,
         prefill_cache=True, page_table=page_table, prefill_len=kv_len,
     )
-    h = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    hl = jnp.take_along_axis(
-        h, last_idx.astype(jnp.int32)[:, None, None], axis=1
-    )[:, 0]                                            # (B, D)
-    logits = L.lm_logits(hl, params["lm_head"])
-    logits = shard(logits, dp_axes(), "model")
+    with jax.named_scope("head"):
+        h = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        hl = jnp.take_along_axis(
+            h, last_idx.astype(jnp.int32)[:, None, None], axis=1
+        )[:, 0]                                        # (B, D)
+        logits = L.lm_logits(hl, params["lm_head"])
+        logits = shard(logits, dp_axes(), "model")
     return logits, new_cache

@@ -101,6 +101,7 @@ BIT-IDENTICAL to the synchronous engine's (tests/test_async_engine.py).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from collections import deque
@@ -169,6 +170,9 @@ _STATS_CONFIG = (
 #: per-step path: async mode pays this INSTEAD of a readback, so its
 #: overhead bounds how much overlap can show up as wall-clock.
 _select_i32 = jax.jit(lambda known, host, dev: jnp.where(known, host, dev))
+
+#: The phase of a step served without telemetry: no span, no annotation.
+_NO_PHASE = contextlib.nullcontext()
 
 
 # ``_drain_point`` - the marker for LEGAL synchronous-readback sites of
@@ -239,9 +243,9 @@ class Request:
     prompt: List[int]
     max_new_tokens: int
     state: str = WAITING
-    # multi-tenant attribution: quota accounting, priority-class ordering,
-    # and per-tenant telemetry.  Purely host-side scheduling inputs - they
-    # never reach the device, so tenant labels cannot change output bits.
+    # multi-tenant attribution: quota accounting and priority-class
+    # ordering.  Purely host-side scheduling inputs - they never reach
+    # the device, so tenant labels cannot change output bits.
     tenant: str = DEFAULT_TENANT
     priority: str = "throughput"
     generated: List[int] = dataclasses.field(default_factory=list)
@@ -326,14 +330,22 @@ def _make_sampler(temperature: float, top_k: int, base_key):
         return jax.random.fold_in(jax.random.fold_in(base_key, rid), idx)
 
     def sample(logits, req_ids, token_idx):
-        lg = logits.astype(jnp.float32) / jnp.asarray(temp, jnp.float32)
-        if top_k > 0:
-            kth = jax.lax.top_k(lg, top_k)[0][..., -1:]
-            lg = jnp.where(lg < kth, -jnp.inf, lg)
-        keys = jax.vmap(keyed)(req_ids, token_idx)
-        return jax.vmap(jax.random.categorical)(keys, lg).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            lg = logits.astype(jnp.float32) / jnp.asarray(temp, jnp.float32)
+            if top_k > 0:
+                kth = jax.lax.top_k(lg, top_k)[0][..., -1:]
+                lg = jnp.where(lg < kth, -jnp.inf, lg)
+            keys = jax.vmap(keyed)(req_ids, token_idx)
+            toks = jax.vmap(jax.random.categorical)(keys, lg)
+            return toks.astype(jnp.int32)
 
     return sample
+
+
+def _greedy(logits):
+    """(logits (B, V)) -> argmax tokens (B,) int32."""
+    with jax.named_scope("sample"):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 class ServeEngine:
@@ -672,8 +684,7 @@ class ServeEngine:
         else:
             def _device_step(params, token, pos, pool, table):
                 logits, new_pool = step(params, token, pos, pool, table)
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return nxt, new_pool
+                return _greedy(logits), new_pool
 
         # Sharded serving (mesh given): the device bodies run under a
         # fully-MANUAL shard_map - no SPMD partitioner ever touches them.
@@ -746,8 +757,7 @@ class ServeEngine:
                     logits, new_pool = pstep(
                         params, tokens, start, kv_len, last, pool, table
                     )
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    return nxt, new_pool
+                    return _greedy(logits), new_pool
 
             if mesh is not None:
                 _device_prefill = jax.shard_map(
@@ -793,7 +803,7 @@ class ServeEngine:
                         rids, idx0 = extra
                         g = sampler(logits, rids, idx0 + i)
                     else:
-                        g = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                        g = _greedy(logits)
                     return pool, (g, phys, pre)
 
                 pool, (gs, physs, pres) = jax.lax.scan(
@@ -926,9 +936,9 @@ class ServeEngine:
 
         ``tenant`` and ``priority`` (one of
         ``scheduler.PRIORITY_CLASSES``) attribute the request for
-        quota-aware policies (``scheduler="tenant"``) and per-tenant
-        telemetry; policies that do not read them behave exactly as
-        before.  They shape latency only - never output bits.
+        quota-aware policies (``scheduler="tenant"``); policies that do
+        not read them behave exactly as before.  They shape latency only
+        - never output bits.
 
         Raises ValueError immediately for requests that could NEVER be
         served - ``len(prompt) + max_new_tokens`` beyond ``max_seq_len`` or
@@ -968,9 +978,7 @@ class ServeEngine:
         r.submit_step = self.steps
         self.waiting.append(r)
         if self.telemetry is not None:
-            self.telemetry.on_submit(
-                r.req_id, self.steps, tenant=r.tenant, priority=r.priority
-            )
+            self.telemetry.on_submit(r.req_id, self.steps)
         return r
 
     # ------------------------------------------------------- policy view --
@@ -1200,7 +1208,7 @@ class ServeEngine:
         self.preemptions += 1
         self.waiting.append(r)
         if self.telemetry is not None:
-            self.telemetry.on_preempt(r.req_id, self.steps, tenant=r.tenant)
+            self.telemetry.on_preempt(r.req_id, self.steps)
 
     def _finish(self, r: Request, *, step: Optional[int] = None) -> None:
         """Finish a request.  ``step`` overrides the stamp for finishes
@@ -1212,9 +1220,7 @@ class ServeEngine:
         r.finish_step = self.steps if step is None else step
         self.finished[r.req_id] = r
         if self.telemetry is not None:
-            self.telemetry.on_finish(
-                r.req_id, r.finish_step, tenant=r.tenant
-            )
+            self.telemetry.on_finish(r.req_id, r.finish_step)
 
     def _account_step_tokens(self, n: int) -> None:
         self.last_step_tokens = int(n)
@@ -1242,26 +1248,24 @@ class ServeEngine:
         tests/test_telemetry.py pins both)."""
         st = self._inflight.popleft()
         emitted = 0
-        by_tenant: Dict[str, int] = {}
         for tok_dev, emits in (
             (st.prefill_tok, st.prefill_emits),
             (st.decode_tok, st.decode_emits),
         ):
             if not emits:
                 continue
-            vals = np.asarray(tok_dev)
+            with self._phase("retire.wait"):
+                vals = np.asarray(tok_dev)
             for r, gen_idx, row in emits:
                 tok = int(vals[row])
                 r.generated[gen_idx] = tok
                 r.pending -= 1
                 emitted += 1
-                by_tenant[r.tenant] = by_tenant.get(r.tenant, 0) + 1
                 if gen_idx == 0 and r.first_token_step < 0:
                     r.first_token_step = st.step_no
                     if self.telemetry is not None:
                         self.telemetry.on_first_token(
-                            r.req_id, r.submit_step, st.step_no,
-                            tenant=r.tenant,
+                            r.req_id, r.submit_step, st.step_no
                         )
                 if self.on_token is not None:
                     self.on_token(r, gen_idx, tok)
@@ -1272,8 +1276,9 @@ class ServeEngine:
         # done - the device restored every rejected position's page
         # bytes before this step's pool left the verify call.
         if st.spec_rows:
-            spec_vals = np.asarray(st.decode_tok)
-            spec_ms = np.asarray(st.verify_m)
+            with self._phase("retire.wait"):
+                spec_vals = np.asarray(st.decode_tok)
+                spec_ms = np.asarray(st.verify_m)
         for r, slot, k in st.spec_rows:
             m = int(spec_ms[slot])
             gen_idx0 = len(r.generated)
@@ -1281,7 +1286,6 @@ class ServeEngine:
                 tok = int(spec_vals[slot, j])
                 r.generated.append(tok)
                 emitted += 1
-                by_tenant[r.tenant] = by_tenant.get(r.tenant, 0) + 1
                 if self.on_token is not None:
                     self.on_token(r, gen_idx0 + j, tok)
             r.cursor += m
@@ -1300,7 +1304,7 @@ class ServeEngine:
             if len(r.generated) >= r.max_new_tokens:
                 self._finish(r, step=st.step_no)
         if emitted and self.telemetry is not None:
-            self.telemetry.on_tokens_emitted(emitted, by_tenant=by_tenant)
+            self.telemetry.on_tokens_emitted(emitted)
 
     def _retire_backlog(self) -> None:
         """Retire down to ``pipeline_depth`` steps in flight (the tail of
@@ -1456,8 +1460,11 @@ class ServeEngine:
             args.extend(self._sample_rows(
                 [(r.req_id, len(r.generated)) for r, _ in rows], pb
             ))
-        first, self.pool = self._device_call(self._prefill_fn, *args)
+        with self._phase("prefill"):
+            first, self.pool = self._device_call(self._prefill_fn, *args)
         st.prefill_tok = first
+        if self.telemetry is not None:
+            self.telemetry.on_prefill(sum(real for _, real in rows), pb * cs)
         completed = []
         scatter: List[Tuple[int, int]] = []   # (slot, output row)
         for i, (r, real) in enumerate(rows):
@@ -1570,27 +1577,47 @@ class ServeEngine:
         for arrival/admission timestamps); the device calls are skipped
         when no request needs them.
         """
-        tel = self.telemetry
-        t0 = tel.clock() if tel is not None else 0.0
-        self._maybe_trim()
-        self._try_admit()
-        # telemetry phase stamps: plan = trim + admission (host-only);
-        # dispatch = per-step table/feed assembly + enqueueing the jitted
-        # calls; retire = materializing steps beyond pipeline_depth.
-        t_plan = tel.clock() if tel is not None else 0.0
+        # phases (telemetry spans + profiler annotations): plan = trim +
+        # admission (host-only); dispatch = per-step table/feed assembly +
+        # enqueueing the jitted calls; retire = materializing steps beyond
+        # pipeline_depth.
+        with self._phase("plan"):
+            self._maybe_trim()
+            self._try_admit()
         live = [r for r in self._slots if r is not None]
         if not live:
             self._account_step_tokens(0)   # idle tick spends nothing
             # nothing to dispatch means nothing to overlap with: drain
             # fully so ``while not eng.idle: eng.step()`` terminates with
             # every placeholder retired (see :meth:`idle`)
-            self.drain()
-            if tel is not None:
-                tel.end_step(self, t0, t_plan, t_plan, 0)
-            self.steps += 1
-            return 0
-        n_live = len(live)
+            with self._phase("retire"):
+                self.drain()
+        else:
+            with self._phase("dispatch"):
+                self._dispatch(live)
+            with self._phase("retire"):
+                self._retire_backlog()
+        if self.telemetry is not None:
+            self.telemetry.end_step(self)
+        self.steps += 1
+        return len(live)
 
+    def _phase(self, name: str):
+        """``with self._phase(name):`` - the telemetry phase (span +
+        profiler annotation) of this step, or nothing without telemetry."""
+        tel = self.telemetry
+        return _NO_PHASE if tel is None else tel.phase(name, self.steps)
+
+    def _gathered_kv(self) -> int:
+        """K/V positions one decode call gathers per layer: every row's
+        whole page table, however few of its positions are live."""
+        return self.max_batch * self.max_pages_per_seq * self.page_size
+
+    def _dispatch(self, live: List[Request]) -> None:
+        """The dispatch phase of :meth:`step`: plan this step's prefill
+        and decode work over the ``live`` requests and enqueue it."""
+        n_live = len(live)
+        tel = self.telemetry
         st = _InflightStep(step_no=self.steps)
         if self.chunked_prefill:
             prefilling = [
@@ -1660,14 +1687,9 @@ class ServeEngine:
                     # count-based backlog alone would never retire the
                     # in-flight verifies, so force retirement here to
                     # make those rows dispatchable again (the verify
-                    # analogue of the idle-tick drain above).
+                    # analogue of the idle-tick drain in step()).
                     self.drain()
-                t_disp = tel.clock() if tel is not None else 0.0
-                self._retire_backlog()
-                if tel is not None:
-                    tel.end_step(self, t0, t_plan, t_disp, n_live)
-                self.steps += 1
-                return n_live
+                return
             # decode view of the table: slots not decoding THIS step
             # (empty, still-prefilling, or budget-deferred) are nulled so
             # the batched scatter cannot touch their pages.
@@ -1712,15 +1734,21 @@ class ServeEngine:
                 for r in dec:
                     pairs[r.slot] = (r.req_id, len(r.generated))
                 args.extend(self._sample_rows(pairs, self.max_batch))
-            (nxt, gtok, m_dev), self.pool = self._device_call(
-                self._verify_fn, *args
-            )
+            with self._phase("decode"):
+                (nxt, gtok, m_dev), self.pool = self._device_call(
+                    self._verify_fn, *args
+                )
             st.decode_tok = gtok
             st.verify_m = m_dev
             self.spec_proposed += n_draft
             self.spec_verify_steps += len(spec_plan)
             if tel is not None:
                 tel.on_spec_dispatch(len(spec_plan), n_draft)
+                sub = np.arange(self.speculate + 1)
+                tel.on_decode(
+                    len(dec), int(((pos[:, None] + sub + 1) * active).sum()),
+                    self._gathered_kv() * (self.speculate + 1),
+                )
         else:
             args = [self.params, feed, jnp.asarray(pos), self.pool,
                     jnp.asarray(table)]
@@ -1729,8 +1757,13 @@ class ServeEngine:
                 for r in dec:
                     pairs[r.slot] = (r.req_id, len(r.generated))
                 args.extend(self._sample_rows(pairs, self.max_batch))
-            nxt, self.pool = self._device_call(self._step_fn, *args)
+            with self._phase("decode"):
+                nxt, self.pool = self._device_call(self._step_fn, *args)
             st.decode_tok = nxt
+            if tel is not None:
+                tel.on_decode(
+                    len(dec), int(pos.sum()) + len(dec), self._gathered_kv()
+                )
         # keep each decoding slot's sampled output resident on device for
         # the NEXT step's feed; non-decoding slots retain their value.
         # On a verify dispatch ``nxt`` is the last ACCEPTED position's
@@ -1774,12 +1807,6 @@ class ServeEngine:
         for r, k, _ in spec_plan:
             st.spec_rows.append((r, r.slot, k))
         self._inflight.append(st)
-        t_disp = tel.clock() if tel is not None else 0.0
-        self._retire_backlog()
-        if tel is not None:
-            tel.end_step(self, t0, t_plan, t_disp, n_live)
-        self.steps += 1
-        return n_live
 
     def run_to_completion(self, max_steps: int = 100_000) -> Dict[int, Request]:
         """Drive :meth:`step` until queue and slots drain.

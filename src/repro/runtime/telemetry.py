@@ -1,15 +1,20 @@
 """Serving-stack observability: step tracing, metrics, numerics telemetry.
 
-Three coupled layers, all dependency-free (stdlib + numpy; jax only at
-the ONE sanctioned device-read site), all **bit-neutral** by
-construction - enabling them changes what the engine *records*, never
-what it *computes*:
+Three coupled layers, all dependency-free (stdlib + numpy; jax only for
+the profiler annotations of the engine's phases and at the ONE
+sanctioned device-read site), all **bit-neutral** by construction -
+enabling them changes what the engine *records*, never what it
+*computes*:
 
   * :class:`StepTracer` - a bounded ring buffer of typed trace events:
-    per-step ``plan`` / ``dispatch`` / ``retire`` spans (wall-clock
-    begin/end + engine step number) and per-request lifecycle instants
-    (``submit`` / ``admit`` / ``resume`` / ``first_token`` / ``preempt``
-    / ``cancel`` / ``finish``).  Exportable as JSON-lines
+    per-step ``plan`` / ``dispatch`` / ``retire`` spans and the spans
+    nested in them (wall-clock begin/end + engine step number; each
+    span is one :meth:`Telemetry.phase`, which also opens a
+    ``serve.<span>`` profiler annotation), per-request lifecycle
+    instants (``submit`` / ``admit`` / ``resume`` / ``first_token`` /
+    ``preempt`` / ``cancel`` / ``finish``), and a per-step ``engine``
+    counter sample (queue, pages, and the work the step dispatched,
+    :data:`WORK_COUNTERS`).  Exportable as JSON-lines
     (:meth:`StepTracer.write_jsonl`) or as a Chrome ``trace_event`` file
     (:meth:`StepTracer.write_chrome_trace`) loadable in Perfetto /
     ``chrome://tracing`` - under async pipelining the trace shows step
@@ -56,6 +61,7 @@ from collections import deque
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 FP16_MAX = 65504.0
 
@@ -80,6 +86,29 @@ def _drain_point(fn):
 #: materializing tokens of steps beyond ``pipeline_depth`` (the only
 #: per-token device wait).
 STEP_SPANS = ("plan", "dispatch", "retire")
+
+#: Spans nested inside them: ``prefill`` / ``decode`` = enqueueing one
+#: jitted call (inside ``dispatch``); ``retire.wait`` = the blocking
+#: readback of a dispatched step's tokens, the host waiting for the device
+#: (inside ``retire``, or inside whatever phase drains the pipeline).
+CHILD_SPANS = ("prefill", "decode", "retire.wait")
+
+#: Every phase also opens a ``jax.profiler.TraceAnnotation`` named
+#: ``serve.<span>``, so a profiler trace of the serving process shows the
+#: engine's phases on the same clock as the device's programs.
+ANNOTATION_PREFIX = "serve."
+
+#: Work counted where it is dispatched, added to each step's ``engine``
+#: counter sample (zero when the step ran no such call): ``decode_rows``
+#: = rows decoding; ``kv_live`` = K/V positions those rows attend
+#: (cursor + 1 each); ``kv_slots`` = K/V positions the decode call gathers
+#: (max_batch x max_pages_per_seq x page_size, whatever is live);
+#: ``prefill_tokens`` = real prompt tokens in the prefill call;
+#: ``prefill_slots`` = its static prefill_batch x prefill_chunk grid.  A
+#: speculative verify counts each of its K+1 decode sub-steps.
+WORK_COUNTERS = (
+    "decode_rows", "kv_live", "kv_slots", "prefill_tokens", "prefill_slots",
+)
 
 #: Request lifecycle instants the engine emits.  ``resume`` is the
 #: re-admission of a previously preempted request; ``first_token`` fires
@@ -234,6 +263,29 @@ class StepTracer:
         with open(path, "w") as f:
             json.dump(payload, f)
         return len(out)
+
+
+class _Phase:
+    """One engine phase: a :class:`StepTracer` span (when tracing) and a
+    profiler annotation ``serve.<name>`` over the same code, on entry and
+    exit of one ``with`` block (:meth:`Telemetry.phase`)."""
+
+    __slots__ = ("tel", "name", "step", "t0", "annotation")
+
+    def __init__(self, tel: "Telemetry", name: str, step: int):
+        self.tel, self.name, self.step = tel, name, step
+
+    def __enter__(self):
+        self.annotation = TraceAnnotation(ANNOTATION_PREFIX + self.name)
+        self.annotation.__enter__()
+        self.t0 = self.tel.clock()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = self.tel.clock()
+        self.annotation.__exit__(*exc)
+        self.tel._close_phase(self.name, self.step, self.t0, t1)
+        return False
 
 
 # ------------------------------------------------------------- metrics --
@@ -618,10 +670,11 @@ class Telemetry:
     own metrics registries; the parent's :meth:`metrics_snapshot`
     aggregates them (:func:`aggregate_snapshots`).
 
-    Every ``on_*`` hook and :meth:`end_step` is host-only (wall clocks +
-    integers the engine already tracks).  The numerics probe is invoked
-    from :meth:`end_step` at the engine's retirement boundary and owns
-    the single sanctioned readback (class docs above).
+    Every ``on_*`` hook, :meth:`phase` and :meth:`end_step` is host-only
+    (wall clocks, profiler annotations, integers the engine already
+    tracks).  The numerics probe is invoked from :meth:`end_step` at the
+    engine's retirement boundary and owns the single sanctioned readback
+    (class docs above).
     """
 
     def __init__(self, *, tracing: bool = True, metrics: bool = True,
@@ -644,6 +697,8 @@ class Telemetry:
         self._children: List["Telemetry"] = []
         self._submit_t: Dict[int, float] = {}
         self._clock_epoch = time.perf_counter()
+        self._work = dict.fromkeys(WORK_COUNTERS, 0)
+        self._step_s = 0.0
         if self.metrics is not None:
             self._install_instruments()
 
@@ -696,7 +751,8 @@ class Telemetry:
           help="submit -> first-token dispatch, in engine steps "
                "(inclusive, the benchmarks' convention)")
         h("serve.step_seconds", unit="s",
-          help="wall-clock duration of step() calls")
+          help="wall-clock duration of step() calls (plan + dispatch + "
+               "retire)")
 
     # --------------------------------------------------------- replicas --
 
@@ -745,34 +801,38 @@ class Telemetry:
         if self.metrics is not None:
             self.metrics.counter(name).inc(n)
 
-    # Per-tenant attribution.  Series are created LAZILY and only for
-    # explicitly-named tenants (tenant != "default"): single-tenant serves
-    # keep the exact metric catalog pinned by tests/test_telemetry.py,
-    # and a fleet pays only for the tenants it actually sees.  The
-    # aggregate serve.* counters always include every tenant's traffic -
-    # the per-tenant series are a breakdown, not a replacement.
+    def phase(self, name: str, step: int) -> _Phase:
+        """``with tel.phase("plan", step):`` - the one way the engine
+        times a phase: a :class:`StepTracer` span named ``name`` (when
+        tracing) and a profiler annotation ``serve.<name>``, both over
+        the block.  Names: :data:`STEP_SPANS`, :data:`CHILD_SPANS`."""
+        return _Phase(self, name, step)
 
-    def _inc_tenant(self, tenant: Optional[str], leaf: str,
-                    n: int = 1) -> None:
-        if (self.metrics is not None and tenant is not None
-                and tenant != "default"):
-            self.metrics.counter(
-                f"serve.tenant.{tenant}.{leaf}",
-                help=f"per-tenant breakdown of serve.* ({leaf})",
-            ).inc(n)
+    def _close_phase(self, name: str, step: int, t0: float,
+                     t1: float) -> None:
+        if name in STEP_SPANS:
+            self._step_s += t1 - t0
+        if self.tracer is not None:
+            self.tracer.span(name, step, t0, t1, engine=self.engine_id)
 
-    def on_submit(self, req_id: int, step: int, *,
-                  tenant: Optional[str] = None,
-                  priority: Optional[str] = None) -> None:
+    def on_decode(self, rows: int, kv_live: int, kv_slots: int) -> None:
+        """A decode (or verify) call was enqueued: rows decoding, the K/V
+        positions they attend, the K/V positions the call gathers."""
+        w = self._work
+        w["decode_rows"] += rows
+        w["kv_live"] += kv_live
+        w["kv_slots"] += kv_slots
+
+    def on_prefill(self, tokens: int, slots: int) -> None:
+        """A prefill call was enqueued: real prompt tokens in it, and the
+        slots of its static grid."""
+        self._work["prefill_tokens"] += tokens
+        self._work["prefill_slots"] += slots
+
+    def on_submit(self, req_id: int, step: int) -> None:
         self._submit_t[req_id] = self.clock()
-        args = {"req_id": req_id}
-        if tenant is not None and tenant != "default":
-            args["tenant"] = tenant
-        if priority is not None:
-            args["priority"] = priority
-        self._instant("submit", step, **args)
+        self._instant("submit", step, req_id=req_id)
         self._inc("serve.requests_submitted")
-        self._inc_tenant(tenant, "submitted")
 
     def on_admit(self, req_id: int, step: int, *, resumed: bool) -> None:
         self._instant(
@@ -782,8 +842,7 @@ class Telemetry:
             self._inc("serve.resumes")
 
     def on_first_token(self, req_id: int, submit_step: int,
-                       dispatch_step: int, *,
-                       tenant: Optional[str] = None) -> None:
+                       dispatch_step: int) -> None:
         """Fired at RETIREMENT (the value exists), stamped with the step
         that dispatched the token - so TTFT-in-steps is pipeline-mode
         -invariant while TTFT-in-seconds honestly includes the async
@@ -792,29 +851,22 @@ class Telemetry:
         if self.metrics is not None:
             ttft = dispatch_step - submit_step + 1
             self.metrics.histogram("serve.ttft_steps").observe(ttft)
-            if tenant is not None and tenant != "default":
-                self.metrics.histogram(
-                    f"serve.tenant.{tenant}.ttft_steps", unit="steps",
-                    help="per-tenant TTFT breakdown (dispatch clock)",
-                ).observe(ttft)
             t0 = self._submit_t.get(req_id)
             if t0 is not None:
                 self.metrics.histogram("serve.ttft_seconds").observe(
                     self.clock() - t0
                 )
 
-    def on_finish(self, req_id: int, step: int, *,
-                  tenant: Optional[str] = None) -> None:
+    def on_finish(self, req_id: int, step: int) -> None:
         self._submit_t.pop(req_id, None)
         self._instant("finish", step, req_id=req_id)
         self._inc("serve.requests_finished")
-        self._inc_tenant(tenant, "finished")
 
-    # Speculative decoding.  Like the per-tenant series, the serve.spec.*
-    # instruments are registered LAZILY on first use: a speculation-off
-    # serve never touches them, so the pinned default catalog
-    # (:meth:`_install_instruments`, tests/test_telemetry.py) stays
-    # intact.  Both hooks read host tallies the engine already computed -
+    # Speculative decoding.  The serve.spec.* instruments are registered
+    # LAZILY on first use: a speculation-off serve never touches them, so
+    # the pinned default catalog (:meth:`_install_instruments`,
+    # tests/test_telemetry.py) stays intact.  Both hooks read host
+    # tallies the engine already computed -
     # nothing here feeds back into a device call, so speculation
     # telemetry is bit-neutral like everything else in this module.
 
@@ -854,11 +906,9 @@ class Telemetry:
             help="accepted draft tokens per retired verify",
         ).observe(accepted)
 
-    def on_preempt(self, req_id: int, step: int, *,
-                   tenant: Optional[str] = None) -> None:
+    def on_preempt(self, req_id: int, step: int) -> None:
         self._instant("preempt", step, req_id=req_id)
         self._inc("serve.preemptions")
-        self._inc_tenant(tenant, "preempted")
 
     def on_cancel(self, req_id: int, step: int) -> None:
         self._submit_t.pop(req_id, None)
@@ -868,39 +918,25 @@ class Telemetry:
     def on_admission_blocked(self, step: int) -> None:
         self._inc("serve.admission_blocked_pages")
 
-    def on_tokens_emitted(
-        self, n: int,
-        by_tenant: Optional[Dict[str, int]] = None,
-    ) -> None:
+    def on_tokens_emitted(self, n: int) -> None:
         self._inc("serve.tokens_emitted", n)
-        if by_tenant:
-            for tenant, cnt in by_tenant.items():
-                self._inc_tenant(tenant, "tokens_emitted", cnt)
 
-    def end_step(self, eng, t0: float, t_plan: float,
-                 t_dispatch: float, n_live: int) -> None:
-        """Close out one engine step: emit the plan/dispatch/retire
-        spans and per-step gauges, then run the numerics probe when due.
-        Called by ``ServeEngine.step()`` with the wall stamps it took at
-        its phase boundaries; everything here is host-only except the
-        probe's sanctioned drain."""
-        t_end = self.clock()
+    def end_step(self, eng) -> None:
+        """Close out one engine step (its phases have recorded their
+        spans): emit the per-step ``engine`` counter sample and gauges,
+        then run the numerics probe when due.  Everything here is
+        host-only except the probe's sanctioned drain."""
         step = eng.steps
         if self.tracer is not None:
-            tr, eid = self.tracer, self.engine_id
-            tr.span("plan", step, t0, t_plan, engine=eid,
-                    args={"live": n_live})
-            if t_dispatch > t_plan:
-                tr.span("dispatch", step, t_plan, t_dispatch, engine=eid,
-                        args={"tokens": eng.last_step_tokens})
-            tr.span("retire", step, t_dispatch, t_end, engine=eid,
-                    args={"inflight": len(eng._inflight)})
-            tr.counter("engine", step, {
+            self.tracer.counter("engine", step, {
                 "waiting": len(eng.waiting),
                 "running": eng.num_running,
                 "free_pages": eng.allocator.free_pages,
                 "inflight": len(eng._inflight),
-            }, engine=eid)
+                **self._work,
+            }, engine=self.engine_id)
+        self._work = dict.fromkeys(WORK_COUNTERS, 0)
+        step_s, self._step_s = self._step_s, 0.0
         if self.metrics is not None:
             m = self.metrics
             allocatable = eng.num_pages - 1
@@ -921,7 +957,7 @@ class Telemetry:
                 m.gauge("prefix.cached_pages").set(
                     eng.prefix_cache.cached_pages
                 )
-            m.histogram("serve.step_seconds").observe(t_end - t0)
+            m.histogram("serve.step_seconds").observe(step_s)
         if self.probe is not None and self.probe.due(step):
             self.sample_numerics(eng)
 

@@ -13,8 +13,7 @@ Three layers:
     streams are bit-identical to the tenant-blind FCFS serve across
     sync/async x {bf16, fp8_e4m3, int8} pool dtypes, through
     preempt-resume and cancel under quota pressure.  Quota withholding
-    must never trigger preemption (withheld != page-starved).  Per
-    -tenant telemetry series appear only for explicitly-labeled tenants.
+    must never trigger preemption (withheld != page-starved).
   * **Routing** (host-side, fake replicas): ``EngineReplicaGroup``
     placement decisions - the least-loaded fallback that closes the
     post-``cancel`` imbalance strict rotation ignored (the PR's
@@ -38,7 +37,6 @@ from repro.runtime import (
     RequestView,
     SchedulerPolicy,
     ServeEngine,
-    Telemetry,
     TenantQuota,
     TenantQuotaPolicy,
     chunked_cold_reference,
@@ -355,36 +353,6 @@ def test_cancel_releases_quota(tiny_bundle, workload):
     assert rb.generated == chunked_cold_reference(
         bundle, params, workload[0], GEN, page_size=8, prefill_chunk=16,
     )
-
-
-def test_per_tenant_telemetry_series(tiny_bundle, workload):
-    """Per-tenant metric series exist exactly for the explicitly-labeled
-    tenants (lazy creation keeps the default catalog pinned), count the
-    right traffic, and the aggregate serve.* counters still include
-    every tenant (the breakdown is additive, not a replacement)."""
-    bundle, params = tiny_bundle
-    tel = Telemetry(tracing=True, metrics=True)
-    _, eng = _serve(
-        bundle, params, workload, scheduler=_tenant_policy(),
-        telemetry=tel, tenants=TENANTS, priorities=PRIOS,
-    )
-    snap = tel.metrics_snapshot()
-    c = snap["counters"]
-    assert c["serve.tenant.bulk.submitted"]["value"] == 2
-    assert c["serve.tenant.interactive.finished"]["value"] == 2
-    assert c["serve.tenant.bulk.tokens_emitted"]["value"] == 2 * GEN
-    assert c["serve.requests_finished"]["value"] == len(workload)
-    assert c["serve.tokens_emitted"]["value"] == len(workload) * GEN
-    assert snap["histograms"]["serve.tenant.interactive.ttft_steps"][
-        "count"] == 2
-    # submit trace events carry the attribution
-    subs = [e for e in tel.tracer.events() if e.name == "submit"]
-    assert {e.args.get("tenant") for e in subs} == {"bulk", "interactive"}
-    # a default-tenant serve creates NO per-tenant series
-    tel2 = Telemetry(metrics=True)
-    _serve(bundle, params, workload[:2], telemetry=tel2)
-    assert not [k for k in tel2.metrics_snapshot()["counters"]
-                if k.startswith("serve.tenant.")]
 
 
 def test_submit_validation(tiny_bundle):

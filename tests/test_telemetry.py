@@ -42,6 +42,12 @@ from repro.runtime import (
     Telemetry,
     aggregate_snapshots,
 )
+from repro.runtime.telemetry import (
+    ANNOTATION_PREFIX,
+    CHILD_SPANS,
+    STEP_SPANS,
+    WORK_COUNTERS,
+)
 
 GEN = 4
 PROMPT_LENS = (37, 21, 45, 12)
@@ -347,6 +353,95 @@ def test_serve_trace_contains_lifecycle_and_spans(tiny_bundle, prompts):
     assert len(by_name["retire"]) == eng.steps
     for e in by_name["plan"]:
         assert e.dur >= 0.0 and e.kind == "span"
+
+
+def test_engine_counter_counts_dispatched_work(tiny_bundle):
+    """The per-step ``engine`` counter carries the work each step
+    dispatched, worked out by hand: max_batch 2 x 4 pages x page 8 = 64
+    K/V positions gathered per decode call, a 2 x 16 prefill grid.
+      step 0: one prefill call, A's first chunk (16) and B's whole prompt
+              (5); B's prompt is done, so B decodes at position 5 (6 live);
+      step 1: A's last 4 prompt tokens; A decodes at 20 (21 live);
+      step 2: A decodes at 21 (22 live) and finishes."""
+    bundle, params = tiny_bundle
+    tel = Telemetry(tracing=True, metrics=False)
+    eng = ServeEngine(
+        bundle, params, max_batch=2, num_pages=16, page_size=8,
+        max_seq_len=32, prefill_chunk=16, pipeline_depth=0, telemetry=tel,
+    )
+    eng.submit(list(range(1, 21)), 3)
+    eng.submit(list(range(1, 6)), 2)
+    eng.run_to_completion()
+    got = [
+        {k: e.args[k] for k in WORK_COUNTERS}
+        for e in tel.tracer.events() if e.kind == "counter"
+    ]
+    row = lambda *v: dict(zip(WORK_COUNTERS, v))  # noqa: E731
+    assert got == [
+        row(1, 6, 64, 21, 32),
+        row(1, 21, 64, 4, 32),
+        row(1, 22, 64, 0, 0),
+    ]
+    counters = [e for e in tel.tracer.events() if e.kind == "counter"]
+    assert set(counters[0].args) == {
+        "waiting", "running", "free_pages", "inflight", *WORK_COUNTERS,
+    }
+
+
+def _profiled_serve(bundle, params, prompts, tmp_path, telemetry):
+    """A serve under ``jax.profiler``: the host events named ``serve.*``
+    as [name, start_ns, end_ns], and the telemetry's spans."""
+    from jax.profiler import ProfileData
+
+    _serve(bundle, params, prompts[:1], telemetry=None)     # compile
+    with jax.profiler.trace(str(tmp_path)):
+        _serve(bundle, params, prompts, telemetry=telemetry, pipeline_depth=1)
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    events = [
+        [ev.name, ev.start_ns, ev.start_ns + ev.duration_ns]
+        for plane in ProfileData.from_file(str(path)).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events
+        if ev.name.startswith("serve.")
+    ]
+    return events
+
+
+def test_phases_are_profiler_annotations(tiny_bundle, prompts, tmp_path):
+    """Each engine phase is one ``with`` block that records its
+    StepTracer span AND a ``serve.<span>`` profiler annotation, so a
+    profiler trace of the serve shows the phases on the device's clock:
+    one annotation per span, children inside their parents."""
+    bundle, params = tiny_bundle
+    tel = Telemetry(tracing=True, metrics=False)
+    events = _profiled_serve(bundle, params, prompts, tmp_path, tel)
+    spans = [e for e in tel.tracer.events() if e.kind == "span"]
+    count = lambda names: {  # noqa: E731
+        n: sum(1 for x in names if x == n) for n in set(names)
+    }
+    assert count([n for n, _, _ in events]) == count(
+        [ANNOTATION_PREFIX + e.name for e in spans]
+    )
+    assert {e.name for e in spans} == set(STEP_SPANS) | set(CHILD_SPANS)
+    by_name = {}
+    for n, s, e in events:
+        by_name.setdefault(n, []).append((s, e))
+
+    def inside(child, parent):
+        return all(
+            any(ps <= s and e <= pe for ps, pe in by_name[parent])
+            for s, e in by_name[child]
+        )
+
+    assert inside("serve.prefill", "serve.dispatch")
+    assert inside("serve.decode", "serve.dispatch")
+    assert inside("serve.retire.wait", "serve.retire")
+    # the step spans tile each step in order
+    steps = [e for e in spans if e.name in STEP_SPANS]
+    assert [e.name for e in steps[:3]] == ["plan", "dispatch", "retire"]
+    # and without telemetry the engine opens no annotation at all
+    assert _profiled_serve(bundle, params, prompts, tmp_path / "off",
+                           None) == []
 
 
 # ------------------------------------------------------- stats schema --

@@ -15,16 +15,14 @@
 #      failures the seed carried were retired in this PR (wide
 #      -accumulation reductions + the fp64/fp32 fixture fix); nothing
 #      gets to regress back onto a tolerated-failure list.
-#   2. The serving-stack observability bound: full telemetry may cost
-#      at most 5% of async wall tokens/sec, checked against the
-#      RECORDED benchmarks/BENCH_serving.json trajectory with
-#      benchmarks/run.py's own checker (run `python -m benchmarks.run`
-#      to re-measure; this gate keeps the committed trajectory honest
-#      without re-running the multi-minute benchmark).
-#   3. The speculative-decoding bound (PR 9): the recorded
-#      spec_decode_on row must be bit-identity-certified and at or
-#      below 0.6 engine steps per token on the repetitive burst -
-#      same recorded-trajectory discipline as the telemetry bound.
+#   2. The speculative-decoding bound (PR 9): the recorded
+#      spec_decode_on row of benchmarks/BENCH_serving.json must be
+#      bit-identity-certified and at or below 0.6 engine steps per token
+#      on the repetitive burst, checked with benchmarks/run.py's own
+#      checker (run `python -m benchmarks.run` to re-measure; this gate
+#      keeps the committed trajectory honest without re-running the
+#      multi-minute benchmark).  The cost of telemetry is measured on the
+#      chip, not gated here (PERF.md).
 #
 # Usage: tools/ci.sh [extra pytest args...]
 #   PER_TEST_TIMEOUT=seconds  override the per-test ceiling (default
@@ -45,19 +43,15 @@ python -m pytest -q \
     -p tools.ci_timeout --per-test-timeout "$PER_TEST_TIMEOUT" \
     "$@"
 
-echo "[ci] telemetry overhead (<= 5%) + spec decode (<= 0.6 steps/token)"
-echo "[ci] bounds on the recorded trajectory"
+echo "[ci] spec decode (<= 0.6 steps/token) bound on the recorded trajectory"
 python - <<'PY'
 import json
 
-from benchmarks.run import (
-    SERVING_JSON, _check_spec_decode, _check_telemetry_overhead,
-)
+from benchmarks.run import SERVING_JSON, _check_spec_decode
 
 with open(SERVING_JSON) as f:
     rows = json.load(f)["rows"]
-_check_telemetry_overhead(rows)
 _check_spec_decode(rows)
 PY
 
-echo "[ci] green: 0 failed, telemetry + spec decode bounds held"
+echo "[ci] green: 0 failed, spec decode bound held"
