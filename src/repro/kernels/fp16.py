@@ -29,36 +29,61 @@ import jax.numpy as jnp
 import numpy as np
 
 _F16 = np.dtype(np.float16)
-_F16_MAX_BITS = 0x477FE000        # 65504.0 as f32 bits
 _F16_MIN_NORMAL = 2.0 ** -14
+_F16_OVERFLOW = 65520.0           # half an ulp past the fp16 maximum: inf
+_SIGN = -(2**31)
+_ABS = 0x7FFFFFFF
+_EXP = 0x7F800000
+_HALF = 0x3F000000                # 0.5 as f32 bits
+
+
+def _bits(x):
+    return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+
+def _f32(bits):
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _store(y, x_abs, sign):
+    """``y`` rounded to fp16 precision, stored: inf where ``|x|`` is past
+    the fp16 range, then ``x``'s sign bit (a zero keeps it too)."""
+    y = jnp.where(x_abs >= _F16_OVERFLOW, jnp.inf, y)
+    return _f32(_bits(y) | sign)
 
 
 def round_f16(x):
     """f32 -> f32 holding ``x.astype(float16)`` exactly (IEEE rounding).
 
-    Normal range: round the f32 mantissa to 10 bits, ties to even, by an
-    integer add on the bit pattern (a carry into the exponent is the
-    correct round-up); results above the fp16 maximum become inf.
-    Subnormal range (|x| < 2**-14): round to a multiple of 2**-24.
-    A scalar comes back as a (1, 1) array: Mosaic bitcasts vectors only.
+    Adding ``c`` = 2**13 fp16 ulps of |x|'s binade leaves the f32 sum an
+    ulp of exactly that fp16 ulp, so the add rounds |x| to fp16 precision,
+    ties to even, and subtracting ``c`` back is exact.  The binade is
+    floored at fp16's smallest normal, so subnormals round to a multiple
+    of 2**-24; past the fp16 range the result is inf whatever ``c`` made
+    of it, and a nan stays nan.  Ten vector-unit ops.  A scalar comes
+    back as a (1, 1) array: Mosaic bitcasts vectors only.
     """
     x = jnp.asarray(x).astype(jnp.float32)
     if x.ndim == 0:
         x = x.reshape(1, 1)
-    u32 = jnp.uint32
-    bits = jax.lax.bitcast_convert_type(x, u32)
-    sign = bits & u32(0x80000000)
-    mag = bits & u32(0x7FFFFFFF)
-    lsb = (mag >> 13) & u32(1)
-    norm = (mag + u32(0xFFF) + lsb) & u32(0xFFFFE000)
-    norm = jnp.where(norm > u32(_F16_MAX_BITS), u32(0x7F800000), norm)
-    norm = jnp.where(mag >= u32(0x7F800000), mag, norm)     # inf / nan
-    ax = jax.lax.bitcast_convert_type(mag, jnp.float32)
-    sub = jax.lax.bitcast_convert_type(
-        jnp.round(ax * 2.0 ** 24) * 2.0 ** -24, u32
-    )
-    out = jnp.where(ax < _F16_MIN_NORMAL, sub, norm) | sign
-    return jax.lax.bitcast_convert_type(out, jnp.float32)
+    bits = _bits(x)
+    ax = _f32(bits & jnp.int32(_ABS))
+    c = jnp.maximum(_f32(bits & jnp.int32(_EXP)), _F16_MIN_NORMAL) * 2.0**13
+    return _store((ax + c) - c, ax, bits & jnp.int32(_SIGN))
+
+
+def bf16_to_f16(x):
+    """bf16 -> f32 holding ``x.astype(float16)`` exactly, cheaper than
+    :func:`round_f16`.  A bf16 value carries 8 significant bits, so only
+    fp16's range rounds it: adding ``c`` = 0.5 with x's sign (an f32 ulp
+    of 2**-24, the fp16 subnormal step) and subtracting it back rounds the
+    subnormal range and leaves every larger bf16 value as it is; past the
+    fp16 maximum it is inf.  (``c`` is built from x's bits: a constant
+    would let the compiler fold the add and subtract away.)"""
+    x = x.astype(jnp.float32)
+    sign = _bits(x) & jnp.int32(_SIGN)
+    c = _f32(sign | jnp.int32(_HALF))
+    return _store((x + c) - c, jnp.abs(x), sign)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,7 +104,11 @@ class KernelNumerics:
 
     def fit(self, x, dtype):
         """``x.astype(dtype)``, in the container ``storage(dtype)``."""
-        return round_f16(x) if self._emulated(dtype) else x.astype(dtype)
+        if not self._emulated(dtype):
+            return x.astype(dtype)
+        if x.dtype == jnp.bfloat16:
+            return bf16_to_f16(x)
+        return round_f16(x)
 
     def const(self, value, dtype):
         """A scalar constant of ``dtype``, in its container."""

@@ -182,7 +182,9 @@ def pasa_paged_decode(
     Passing the four sidecar arrays selects the quantized-pool mode: pages
     are fp8/int8 shift-centered codes (runtime/paged_cache.py), dequantized
     in VMEM (kernel) / post-gather (XLA fallback) at
-    ``policy.input_dtype``.
+    ``policy.input_dtype``.  The kernel reads a raw pool in its stored
+    dtype and converts each page to ``policy.input_dtype`` in VMEM, the
+    same rounding as the fallback's cast of the gathered pages.
     """
     if q.ndim != 4:
         raise ValueError("q must be (B, KVH, G, D)")
@@ -192,10 +194,10 @@ def pasa_paged_decode(
             f"{v_pages.shape}"
         )
     quant = _check_quant(k_pages, (k_scale, k_shift, v_scale, v_shift))
-    if not quant:
-        k_pages = k_pages.astype(policy.input_dtype)
-        v_pages = v_pages.astype(policy.input_dtype)
     if not use_kernel:
+        if not quant:
+            k_pages = k_pages.astype(policy.input_dtype)
+            v_pages = v_pages.astype(policy.input_dtype)
         return _paged.paged_decode_xla(
             q.astype(policy.input_dtype),
             k_pages, v_pages,

@@ -45,21 +45,23 @@ def init_decode_scratch(m_scr, l_scr, f_scr, cnt_scr, acc_scr):
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
 
-def decode_scratch(g, d, stat_dtype, acc_dtype, num: KernelNumerics):
-    """Running decode state: m, l, F-bar (lane-replicated), the processed
-    block count, and the (G, d) output accumulator."""
+def decode_scratch(rows, d, stat_dtype, acc_dtype, num: KernelNumerics):
+    """Running decode state of ``rows`` query rows: m, l, F-bar
+    (lane-replicated), the processed block count, and the (rows, d) output
+    accumulator."""
     stat = num.storage(stat_dtype)
     return [
-        pltpu.VMEM((g, _LANES), stat),
-        pltpu.VMEM((g, _LANES), stat),
-        pltpu.VMEM((g, _LANES), stat),
+        pltpu.VMEM((rows, _LANES), stat),
+        pltpu.VMEM((rows, _LANES), stat),
+        pltpu.VMEM((rows, _LANES), stat),
         pltpu.SMEM((1, 1), jnp.int32),
-        pltpu.VMEM((g, d), num.storage(acc_dtype)),
+        pltpu.VMEM((rows, d), num.storage(acc_dtype)),
     ]
 
 
 def masked_block_update(
-    q, k, v,               # (G, d), (block, d), (block, d) VMEM values
+    q,                     # (R, d) query rows of H kv heads, head-major
+    ks, vs,                # H (block, d) key and value blocks at in_dtype
     kv_len,                # scalar int32 valid length of this sequence
     col0,                  # first global column of this block (j * block)
     block: int,
@@ -73,72 +75,83 @@ def masked_block_update(
     score_dtype,
     num: KernelNumerics,
 ):
-    """Fold one KV block into the running decode state (shared kernel body).
+    """Fold one KV block of each of H kv heads into the running decode
+    state (shared kernel body).
 
     The algebraic-shift/masked-mean update of the module doc: per-block key
     mean and row pseudo-average over the *valid* (col < kv_len) columns
-    only.  Used bit-identically by the contiguous decode kernel (block ==
-    block_kv) and the paged decode kernel (block == page_size) - keeping
-    this in ONE place is what makes the two kernels' outputs comparable
-    bit-for-bit (tests/test_paged.py).
+    only.  Used bit-identically by the contiguous decode kernel (one head,
+    block == block_kv) and the paged decode kernel (every kv head of a
+    page, block == page_size) - keeping this in ONE place is what makes the
+    two kernels' outputs comparable bit-for-bit (tests/test_paged.py).
+
+    Rows h * G .. h * G + G - 1 of ``q`` and of the state belong to head
+    h.  Only the key shift and the two block contractions run per head;
+    their rows are stacked, so the softmax statistics and the online
+    update - a long chain of small fp16-rounded steps - run once for all
+    heads' rows.
 
     Reductions (count, key mean, row mean, softmax sum) accumulate at the
     wide dtype and round once on the store - see
     ``repro.core.precision.reduce_dtype``.  Accumulating them at an fp16
     ``stat_dtype`` is order-sensitive: the Mosaic lowering and the XLA
     reference round the *same* expressions differently (observed 3e-3 on
-    decode outputs), which breaks the kernel==reference contract.  The
-    sums are expressed as ones-vector ``dot_general`` contractions, not
-    vector-unit reduces: a GEMM's accumulation order is fixed by its
-    (static) shapes, while a ``reduce`` lowers with layout-dependent
-    order - the paged and contiguous kernels feed this function blocks
-    gathered from different memory layouts, and their outputs must stay
-    bit-for-bit equal (tests/test_paged.py).
+    decode outputs), which breaks the kernel==reference contract.  They
+    are vector-unit sums over a (block, d) or (R, block) value, which both
+    kernels hand this function in one shape and tiling; a ones-vector
+    contraction would cost the matrix unit a whole pass over the block.
 
     ``num`` holds the policy dtypes (kernels/fp16.py): every named
     quantity below is stored at its policy dtype through ``num.fit``.
     """
-    d = q.shape[-1]
+    rows, d = q.shape
+    g = rows // len(ks)
     wide = reduce_dtype(stat_dtype)
     scale = jnp.asarray(1.0 / np.sqrt(d), wide)
-    prec = num.precision
 
     def fit_stat(x):
         return num.fit(x, stat_dtype)
 
+    def per_head(lhs, blocks, dims):
+        """Head h's rows of ``lhs`` contracted with ``blocks[h]``, stacked."""
+        return jnp.concatenate([
+            jax.lax.dot_general(
+                lhs[h * g:(h + 1) * g], block, dims,
+                preferred_element_type=jnp.float32, precision=num.precision,
+            )
+            for h, block in enumerate(blocks)
+        ], axis=0)
+
     cols = col0 + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
     valid = cols < kv_len                              # (block, 1)
-    ones = jnp.ones((block, 1), wide)
     # integer-valued -> exact at wide regardless of order
     count = jnp.sum(valid.astype(wide))
 
-    if beta > 0.0:
+    def shifted(k):
+        if beta <= 0.0:
+            return num.fit(k.astype(wide) * scale, in_dtype)
         # Masked per-block key mean (algebraic shift; see module doc).
-        km = jax.lax.dot_general(
-            ones, jnp.where(valid, k.astype(wide), 0.0),
-            (((0,), (0,)), ((), ())), preferred_element_type=wide,
-            precision=prec,
+        km = jnp.sum(
+            jnp.where(valid, k.astype(wide), 0.0), axis=0, keepdims=True
         ) / count                                      # (1, d)
-        k_sh = num.fit(
+        return num.fit(
             (k.astype(wide) - jnp.asarray(beta, wide) * km) * scale, in_dtype
         )
-    else:
-        k_sh = num.fit(k.astype(wide) * scale, in_dtype)
 
-    s = num.fit(jax.lax.dot_general(
-        q, k_sh, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=prec,
-    ), score_dtype)                                    # (G, block)
+    s = num.fit(per_head(
+        q, [shifted(k) for k in ks], (((1,), (1,)), ((), ()))
+    ), score_dtype)                                    # (R, block)
 
-    vmask = valid[:, 0][None, :]                       # (1, block)
+    # The same mask along lanes, built as a row: ``valid`` turned into a
+    # row gets a Mosaic layout that takes a vector register per 8 scores
+    # of every (R, block) value it touches (8x the kernel's time).
+    vmask = col0 + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    vmask = vmask < kv_len                             # (1, block)
     # Masked row mean over the *valid* columns only (matches the shift).
     sbar = fit_stat(
-        jax.lax.dot_general(
-            jnp.where(vmask, s.astype(wide), 0.0), ones,
-            (((1,), (0,)), ((), ())), preferred_element_type=wide,
-            precision=prec,
-        ) / count
-    )                                                  # (G, 1)
+        jnp.sum(jnp.where(vmask, s.astype(wide), 0.0), axis=1, keepdims=True)
+        / count
+    )                                                  # (R, 1)
     s = jnp.where(vmask, s, jnp.asarray(NEG_BIG, s.dtype))
 
     s_stat = fit_stat(s)
@@ -147,10 +160,9 @@ def masked_block_update(
     )
     p = num.fit(jnp.exp(fit_stat(s_stat - m_loc)), score_dtype)
     p = jnp.where(vmask, p, jnp.asarray(0.0, p.dtype))
-    l_loc = fit_stat(jax.lax.dot_general(
-        p.astype(wide), ones, (((1,), (0,)), ((), ())),
-        preferred_element_type=wide, precision=prec,
-    ))                                                 # (G, 1)
+    l_loc = fit_stat(
+        jnp.sum(p.astype(wide), axis=1, keepdims=True)
+    )                                                  # (R, 1)
 
     cnt = cnt_scr[0, 0]
     m_new, l_new, f_new, e_prev, e_cur = online_update(
@@ -160,14 +172,18 @@ def masked_block_update(
     if inva != 0.0:
         f_scr[...] = jnp.broadcast_to(f_new, f_scr.shape)
 
-    # Zero v at invalid columns BEFORE the PV GEMM: p is already 0 there,
-    # but 0 * NaN = NaN inside the contraction, so non-finite stale values
-    # in recycled (unscrubbed) pages would poison pv through the dot.
-    v_live = jnp.where(valid, v, jnp.asarray(0.0, v.dtype))
-    pv = num.fit(jax.lax.dot_general(
-        p, num.fit(v_live, score_dtype), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=prec,
-    ), acc_dtype)
+    def live(v):
+        # Zero v at invalid columns BEFORE the PV GEMM: p is already 0
+        # there, but 0 * NaN = NaN inside the contraction, so non-finite
+        # stale values in recycled (unscrubbed) pages would poison pv.
+        v = jnp.where(valid, v, jnp.asarray(0.0, v.dtype))
+        if np.dtype(score_dtype) != np.dtype(in_dtype):
+            v = num.fit(v, score_dtype)
+        return v
+
+    pv = num.fit(per_head(
+        p, [live(v) for v in vs], (((1,), (0,)), ((), ()))
+    ), acc_dtype)                                      # (R, d)
     acc_scr[...] = accumulate(
         acc_scr[...], e_prev, e_cur, pv, acc_dtype=acc_dtype, num=num
     )
@@ -254,7 +270,7 @@ def _decode_kernel(
     @pl.when(j * block_kv < kv_len)
     def _step():
         masked_block_update(
-            q_ref[0, 0], k_ref[0, 0], v_ref[0, 0],
+            q_ref[0, 0], [k_ref[0, 0]], [v_ref[0, 0]],
             kv_len, j * block_kv, block_kv,
             m_scr, l_scr, f_scr, cnt_scr, acc_scr,
             inva=inva, beta=beta, in_dtype=in_dtype, stat_dtype=stat_dtype,

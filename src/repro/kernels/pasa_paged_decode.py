@@ -7,18 +7,24 @@ grid into a physical page id *before* the DMA pipeline issues - the gather
 costs zero extra HBM traffic versus the contiguous kernel (each page is
 fetched exactly once, straight into VMEM).
 
-Algorithm identity: one grid step processes one page.  Because the engine
-fixes ``page_size`` to the PASA block length, the kernel body is the same
+Algorithm identity: one grid step processes one page of every kv head.
+With ``page_size`` equal to the PASA block length, the body runs, for each
+kv head on its lane-aligned ``D``-wide slice of the page, the same
 algebraic-shift/masked-mean block update as the contiguous decode kernel
 (module doc there): the per-page key mean uses only valid (pos < kv_len)
-columns, the row pseudo-average S-bar is over the same columns, and the
-running (m, l, F-bar, acc) state lives in VMEM scratch across the page sweep.
-Stale contents of recycled pages beyond ``kv_len`` are therefore
-mathematically inert - no page scrubbing on free.
+columns, the row pseudo-average S-bar is over the same columns, and each
+head's running (m, l, F-bar, acc) state lives in VMEM scratch across the
+page sweep.  Stale contents of recycled pages beyond ``kv_len`` are
+therefore mathematically inert - no page scrubbing on free.
 
-Pages fully past ``kv_len`` are skipped via ``pl.when`` (their page-table
-entries point at the null page 0, a valid DMA target); valid pages of a
-sequence always form a prefix of its page table.
+The pool is read in its stored dtype (bf16 in serving): each page block is
+converted to the policy's input dtype in VMEM, where it is loaded, so no
+widened copy of the pool is ever written to HBM.
+
+Valid pages of a sequence always form a prefix of its page table.  The
+index maps clamp the page index to the row's last live page, so every grid
+step past it repeats that block index and the pipeline issues no DMA for
+it; ``pl.when`` skips its compute.
 
 Quantized pools (``runtime/paged_cache.py``): when the per-page sidecar
 arrays (scale/shift) are passed, the K/V blocks arrive as fp8/int8 codes
@@ -29,7 +35,7 @@ HBM read is 8-bit, and the shift-centered values never exist at high
 precision outside the kernel.  Pages past ``kv_len`` are skipped before
 their (possibly NaN-poisoned) sidecars are ever used.
 
-Grid: (B, KVH, max_pages) with the page dimension innermost/"arbitrary".
+Grid: (B, max_pages) with the page dimension innermost/"arbitrary".
 
 The XLA fallback (:func:`paged_decode_xla`) is a ``jnp.take`` gather of the
 pages followed by ``core.pasa.blocked_attention`` at the matching
@@ -62,20 +68,22 @@ def dequant_block(codes, scale, shift, deq_dtype, num: KernelNumerics):
     return num.fit(codes.astype(jnp.float32) * scale + shift, deq_dtype)
 
 
-def pool_blocks(k_pages, v_pages, quant, page_map):
+def pool_blocks(k_pages, v_pages, quant, page_map, lanes=None):
     """Lay the pool and its sidecars out for lane-aligned page blocks.
 
     The pool (P, page, KVH, D) is viewed as (P, page, KVH * D), so one
     (page, D) block of kv head ``h`` is lane block ``h`` - a slice the TPU
     tiling accepts (a block of 1 along the KVH axis would sit in the
-    second-minor position).  Scales (P, KVH) become (P, 1, KVH) rows read
-    whole and picked by :func:`head_scale`; shifts (P, KVH, D) become
-    (P, 1, KVH * D) with the same lane-block index as the pages.
-    ``page_map(*grid_and_prefetch) -> (physical page, kv head)``.
-    Returns (in_specs, inputs) for the K/V blocks and, when ``quant`` is
-    given, the k_scale/k_shift/v_scale/v_shift blocks.
+    second-minor position).  ``lanes`` is the block's width (default ``D``,
+    one head; ``KVH * D`` takes the whole page).  Scales (P, KVH) become
+    (P, 1, KVH) rows read whole and picked by :func:`head_scale`; shifts
+    (P, KVH, D) become (P, 1, KVH * D) with the same lane-block index as
+    the pages.  ``page_map(*grid_and_prefetch) -> (physical page, lane
+    block)``.  Returns (in_specs, inputs) for the K/V blocks and, when
+    ``quant`` is given, the k_scale/k_shift/v_scale/v_shift blocks.
     """
     p, page, kvh, d = k_pages.shape
+    lanes = lanes or d
 
     def kv_map(*idx):
         pg, h = page_map(*idx)
@@ -85,14 +93,14 @@ def pool_blocks(k_pages, v_pages, quant, page_map):
         pg, _ = page_map(*idx)
         return (pg, 0, 0)
 
-    kv_spec = pl.BlockSpec((1, page, d), kv_map)
+    kv_spec = pl.BlockSpec((1, page, lanes), kv_map)
     in_specs = [kv_spec, kv_spec]
     inputs = [
         k_pages.reshape(p, page, kvh * d), v_pages.reshape(p, page, kvh * d)
     ]
     if quant:
         sc_spec = pl.BlockSpec((1, 1, kvh), sc_map)
-        sh_spec = pl.BlockSpec((1, 1, d), kv_map)
+        sh_spec = pl.BlockSpec((1, 1, lanes), kv_map)
         in_specs += [sc_spec, sh_spec, sc_spec, sh_spec]
         inputs += [
             quant["k_scale"].reshape(p, 1, kvh),
@@ -123,6 +131,8 @@ def _paged_decode_kernel(
     beta: float,
     page_size: int,
     n_pages: int,
+    kv_heads: int,
+    head_dim: int,
     stat_dtype,
     acc_dtype,
     score_dtype,
@@ -132,15 +142,15 @@ def _paged_decode_kernel(
     num: KernelNumerics,
 ):
     if quantized:
-        # (1,1,G,D), (1,page,D) codes x2, (1,1,KVH) scale x2, (1,1,D) shift x2
+        # (1,KVH*G,D), (1,page,KVH*D) codes x2, (1,1,KVH) scale x2,
+        # (1,1,KVH*D) shift x2
         (q_ref, k_ref, v_ref, ks_ref, kh_ref, vs_ref, vh_ref,
          o_ref, m_scr, l_scr, f_scr, cnt_scr, acc_scr) = refs
     else:
         (q_ref, k_ref, v_ref,
          o_ref, m_scr, l_scr, f_scr, cnt_scr, acc_scr) = refs
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     kv_len = kv_len_ref[b]
 
     @pl.when(j == 0)
@@ -149,20 +159,30 @@ def _paged_decode_kernel(
 
     @pl.when(j * page_size < kv_len)
     def _step():
-        # One page == one PASA block: the shared block update (the SAME
-        # code the contiguous decode kernel runs, see pasa_decode.py) with
-        # the page's global column offset.
-        k = k_ref[0]
-        v = v_ref[0]
-        if quantized:
-            k = dequant_block(
-                k, head_scale(ks_ref[0], h), kh_ref[0], deq_dtype, num
-            )
-            v = dequant_block(
-                v, head_scale(vs_ref[0], h), vh_ref[0], deq_dtype, num
-            )
+        # One page == one PASA block of every kv head: the shared block
+        # update (the SAME code the contiguous decode kernel runs, see
+        # pasa_decode.py) with the page's global column offset.
+        ks, vs = [], []
+        for h in range(kv_heads):
+            lanes = slice(h * head_dim, (h + 1) * head_dim)
+            k = k_ref[0, :, lanes]
+            v = v_ref[0, :, lanes]
+            if quantized:
+                k = dequant_block(
+                    k, head_scale(ks_ref[0], h), kh_ref[0, :, lanes],
+                    deq_dtype, num,
+                )
+                v = dequant_block(
+                    v, head_scale(vs_ref[0], h), vh_ref[0, :, lanes],
+                    deq_dtype, num,
+                )
+            else:
+                k = num.fit(k, deq_dtype)
+                v = num.fit(v, deq_dtype)
+            ks.append(k)
+            vs.append(v)
         masked_block_update(
-            q_ref[0, 0], k, v,
+            q_ref[0], ks, vs,
             kv_len, j * page_size, page_size,
             m_scr, l_scr, f_scr, cnt_scr, acc_scr,
             inva=inva, beta=beta, in_dtype=deq_dtype, stat_dtype=stat_dtype,
@@ -172,7 +192,7 @@ def _paged_decode_kernel(
     @pl.when(j == n_pages - 1)
     def _fin():
         l = num.fit(l_scr[:, :1], acc_dtype)
-        o_ref[0, 0] = num.fit(acc_scr[...] / l, out_dtype)
+        o_ref[0] = num.fit(acc_scr[...] / l, out_dtype)
 
 
 @functools.partial(
@@ -184,8 +204,8 @@ def _paged_decode_kernel(
 )
 def paged_decode_kernel_call(
     q: jnp.ndarray,          # (B, KVH, G, D) - one new token, grouped heads
-    k_pages: jnp.ndarray,    # (P, page, KVH, D) physical page pool (raw or
-    v_pages: jnp.ndarray,    # (P, page, KVH, D)   quantized codes)
+    k_pages: jnp.ndarray,    # (P, page, KVH, D) physical page pool in its
+    v_pages: jnp.ndarray,    # (P, page, KVH, D)   stored dtype (or codes)
     page_table: jnp.ndarray, # (B, max_pages) int32 physical page ids
     kv_len: jnp.ndarray,     # (B,) int32 valid lengths
     *,
@@ -211,50 +231,56 @@ def paged_decode_kernel_call(
     kernel = functools.partial(
         _paged_decode_kernel,
         inva=inva, beta=beta, page_size=page_size, n_pages=n_pages,
+        kv_heads=kvh, head_dim=d,
         stat_dtype=stat_dtype, acc_dtype=acc_dtype, score_dtype=score_dtype,
         out_dtype=out_dtype, quantized=quantized, deq_dtype=deq_dtype,
         num=num,
     )
 
-    # The page gather: physical page id read from the prefetched table
-    # inside the index map, before the DMA is issued.  Sidecars ride the
-    # same gather; one (scalar, vector) pair per (page, kv-head).
+    def page_map(b_, j, kvl, pt):
+        # The page gather: the physical page id is read from the prefetched
+        # table inside the index map, before the DMA is issued.  Steps past
+        # the row's last live page repeat its index, so they fetch nothing.
+        last = jnp.maximum(kvl[b_] - 1, 0) // page_size
+        return pt[b_, jnp.minimum(j, last)], 0
+
+    # Sidecars ride the same gather: one scale row and one shift row of
+    # every kv head per page.
     quant = (
         dict(k_scale=k_scale, k_shift=k_shift, v_scale=v_scale,
              v_shift=v_shift) if quantized else None
     )
     kv_specs, kv_inputs = pool_blocks(
-        num.to_storage(k_pages), num.to_storage(v_pages), quant,
-        lambda b_, h, j, kvl, pt: (pt[b_, j], h),
+        num.to_storage(k_pages), num.to_storage(v_pages), quant, page_map,
+        lanes=kvh * d,
     )
-    in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda b_, h, j, kvl, pt: (b_, h, 0, 0)),
-    ] + kv_specs
-    inputs = [num.to_storage(q)] + kv_inputs
-
+    # every kv head's query rows, head-major, in one block
+    rows = pl.BlockSpec((1, kvh * g, d), lambda b_, j, kvl, pt: (b_, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, n_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, g, d), lambda b_, h, j, kvl, pt: (b_, h, 0, 0)
+        grid=(b, n_pages),
+        in_specs=[rows] + kv_specs,
+        out_specs=rows,
+        scratch_shapes=decode_scratch(
+            kvh * g, d, stat_dtype, acc_dtype, num
         ),
-        scratch_shapes=decode_scratch(g, d, stat_dtype, acc_dtype, num),
     )
 
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), num.storage(out_dtype)),
+        out_shape=jax.ShapeDtypeStruct(
+            (b, kvh * g, d), num.storage(out_dtype)
+        ),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(
         kv_len.astype(jnp.int32), page_table.astype(jnp.int32),
-        *inputs,
+        num.to_storage(q.reshape(b, kvh * g, d)), *kv_inputs,
     )
-    return out.astype(out_dtype)
+    return out.reshape(b, kvh, g, d).astype(out_dtype)
 
 
 def _gather_dequant(pages, scale, shift, page_table, deq_dtype):

@@ -20,6 +20,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core import blocked_attention, naive_attention
@@ -125,6 +126,69 @@ def _gather_kv(pool, page_table):
     return tuple(gather_pages(pool[n], page_table) for n in ("k", "v"))
 
 
+def _paged_decode_backend() -> str:
+    """The paged decode route this backend takes: "kernel" on a TPU, "xla"
+    elsewhere.  The one seam tests monkeypatch: "interpret" runs the
+    kernel in the Pallas interpreter on the CPU, and "kernel" lets a CPU
+    host compile the TPU program for a described chip."""
+    return "kernel" if jax.default_backend() == "tpu" else "xla"
+
+
+def paged_decode_route(cfg: ModelConfig, page_size: int) -> str:
+    """How a paged decode step reads this layer's pool.
+
+    "xla": gather every row's whole page table (``_gather_kv``) and run
+    ``blocked_attention`` over it.  "kernel" / "interpret": the paged
+    decode kernel (kernels/pasa_paged_decode.py) reads each row's live
+    pages in place.  The kernel computes the same attention, at the same
+    fp16 storage points, where one page is one PASA block of the
+    algebraic shift; anything else takes the XLA route on every backend.
+    """
+    ac = cfg.attention
+    if ac.impl != "pasa" or page_size != ac.block_kv:
+        return "xla"
+    return _paged_decode_backend()
+
+
+def paged_decode_reads(cfg: ModelConfig, page_size: int, pos,
+                       max_pages: int) -> int:
+    """K/V positions one paged decode call reads from a layer's pool, for
+    write positions ``pos`` (0 in slots not decoding).  The XLA route
+    gathers every row's whole page table, however few of its positions
+    are live; the kernel's index map stops at each row's last live page,
+    ``kv_len = pos + 1``, so an idle slot reads one null page."""
+    pos = np.asarray(pos, np.int64)
+    if paged_decode_route(cfg, page_size) == "xla":
+        return pos.size * max_pages * page_size
+    return int((-(-(pos + 1) // page_size)).sum()) * page_size
+
+
+def _paged_decode(q, pool, page_table, kv_len, cfg: ModelConfig, *,
+                  interpret: bool):
+    """The paged decode kernel over this layer's pool: q (B, 1, H, hd)
+    -> (B, KVH, G, hd) at the PASA policy's output dtype."""
+    from repro.kernels.ops import pasa_paged_decode
+
+    b, _, _, hd = q.shape
+    kvh = cfg.n_kv_heads
+    n_pages, page, _ = pool["k"].shape
+    quant = {}
+    if "k_scale" in pool:
+        quant = dict(
+            k_scale=pool["k_scale"], v_scale=pool["v_scale"],
+            k_shift=pool["k_shift"].reshape(n_pages, kvh, hd),
+            v_shift=pool["v_shift"].reshape(n_pages, kvh, hd),
+        )
+    ac = cfg.attention
+    return pasa_paged_decode(
+        q.reshape(b, kvh, cfg.group, hd),
+        pool["k"].reshape(n_pages, page, kvh, hd),
+        pool["v"].reshape(n_pages, page, kvh, hd),
+        page_table, kv_len, beta=ac.beta, policy=get_policy(ac.pasa_policy),
+        interpret=interpret, **quant,
+    )
+
+
 def attention(
     x: jnp.ndarray,                 # (B, S, D)
     p,                              # params (single layer slice)
@@ -194,6 +258,7 @@ def attention(
                 k = apply_rope(k, cos, sin)
 
     new_cache = None
+    out = None
     chunk_block = 0
     quantized = cache is not None and "k_scale" in cache
     if cache is not None and page_table is not None and prefill_cache:
@@ -305,10 +370,10 @@ def attention(
         # page_table[b, pos // page] at slot pos % page; inactive batch
         # slots carry page_table rows of null pages (page 0), so their
         # writes land in the reserved sink and the pool stays consistent.
-        # The read is the XLA gather (jnp.take of each sequence's pages)
-        # on every platform: the served path calls no Pallas kernel
-        # (kernels/pasa_paged_decode.py computes the same attention and is
-        # validated against this gather path, but nothing routes here).
+        # The read follows ``paged_decode_route``: on a TPU the paged
+        # decode kernel reads each row's live pages in the pool's stored
+        # dtype; elsewhere the XLA gather (jnp.take of each sequence's
+        # pages) feeds blocked_attention.
         from repro.runtime.paged_cache import (
             dequantize_kv_page,
             quantize_kv_page,
@@ -370,13 +435,21 @@ def attention(
                     v.reshape(b, kvh * hd).astype(cv.dtype)
                 )
                 new_cache = {"k": ck, "v": cv}
-        with jax.named_scope("kv_gather"):
-            kseq, vseq = _gather_kv(new_cache, page_table)
-            s2 = kseq.shape[1]
-            k = kseq.reshape(b, s2, kvh, hd).astype(cd)
-            v = vseq.reshape(b, s2, kvh, hd).astype(cd)
         kv_len = (pos + 1).astype(jnp.int32)
         causal = False  # kv_len mask subsumes causality for 1-token steps
+        route = paged_decode_route(cfg, page)
+        if route == "xla":
+            with jax.named_scope("kv_gather"):
+                kseq, vseq = _gather_kv(new_cache, page_table)
+                s2 = kseq.shape[1]
+                k = kseq.reshape(b, s2, kvh, hd).astype(cd)
+                v = vseq.reshape(b, s2, kvh, hd).astype(cd)
+        else:
+            with jax.named_scope("pasa"):
+                out = _paged_decode(
+                    q, new_cache, page_table, kv_len, cfg,
+                    interpret=route == "interpret",
+                )
     elif cache is not None:
         ck, cv = cache["k"], cache["v"]
         if prefill_cache:
@@ -408,6 +481,7 @@ def attention(
             kv_len = (pos + 1).astype(jnp.int32)
             causal = False  # kv_len mask subsumes causality for 1-token steps
 
+    decode_path = cache is not None and not prefill_cache
     # Layout choice (EXPERIMENTS.md section Perf, iteration 1):
     #  * train/prefill: expand KV to the full H heads so q/k/v share the
     #    (B, H, S, hd) layout - all attention einsum dims are batch or
@@ -420,52 +494,52 @@ def attention(
     # No explicit per-head sharding constraints in either path: uneven
     # kvh-over-model constraints cause involuntary full rematerialization
     # copies (verified in the dry-run; see EXPERIMENTS.md).
-    with jax.named_scope("pasa"):
-        decode_path = cache is not None and not prefill_cache
-        if cfg.attention.expand_kv and not decode_path and g > 1:
-            k = jnp.broadcast_to(
-                k[:, :, :, None], (b, k.shape[1], kvh, g, hd)
-            ).reshape(b, k.shape[1], h, hd)
-            v = jnp.broadcast_to(
-                v[:, :, :, None], (b, v.shape[1], kvh, g, hd)
-            ).reshape(b, v.shape[1], h, hd)
-            q5 = jnp.moveaxis(q, 2, 1)              # (B, H, S, hd)
-            k5 = jnp.moveaxis(k, 2, 1)
-            v5 = jnp.moveaxis(v, 2, 1)
-            # Matching (possibly uneven) H-over-model constraints on all
-            # three operands: keeps GSPMD from splitting the head_dim
-            # contraction, which otherwise inserts one (B,H,S,hd)
-            # all-reduce per KV block per layer (the dominant baseline
-            # collective; EXPERIMENTS.md section Perf iteration 1).
-            q5 = shard(q5, dp_axes(), "model", None, None)
-            k5 = shard(k5, dp_axes(), "model", None, None)
-            v5 = shard(v5, dp_axes(), "model", None, None)
-            out_heads_axis = 1
-        else:
-            q5 = jnp.moveaxis(q, 2, 1).reshape(b, kvh, g, s, hd)
-            k5 = jnp.moveaxis(k, 2, 1)[:, :, None]
-            v5 = jnp.moveaxis(v, 2, 1)[:, :, None]
-            out_heads_axis = None
+    if out is None:
+        with jax.named_scope("pasa"):
+            if cfg.attention.expand_kv and not decode_path and g > 1:
+                k = jnp.broadcast_to(
+                    k[:, :, :, None], (b, k.shape[1], kvh, g, hd)
+                ).reshape(b, k.shape[1], h, hd)
+                v = jnp.broadcast_to(
+                    v[:, :, :, None], (b, v.shape[1], kvh, g, hd)
+                ).reshape(b, v.shape[1], h, hd)
+                q5 = jnp.moveaxis(q, 2, 1)              # (B, H, S, hd)
+                k5 = jnp.moveaxis(k, 2, 1)
+                v5 = jnp.moveaxis(v, 2, 1)
+                # Matching (possibly uneven) H-over-model constraints on all
+                # three operands: keeps GSPMD from splitting the head_dim
+                # contraction, which otherwise inserts one (B,H,S,hd)
+                # all-reduce per KV block per layer (the dominant baseline
+                # collective; EXPERIMENTS.md section Perf iteration 1).
+                q5 = shard(q5, dp_axes(), "model", None, None)
+                k5 = shard(k5, dp_axes(), "model", None, None)
+                v5 = shard(v5, dp_axes(), "model", None, None)
+                out_heads_axis = 1
+            else:
+                q5 = jnp.moveaxis(q, 2, 1).reshape(b, kvh, g, s, hd)
+                k5 = jnp.moveaxis(k, 2, 1)[:, :, None]
+                v5 = jnp.moveaxis(v, 2, 1)[:, :, None]
+                out_heads_axis = None
 
-        kv_len_b = None
-        if kv_len is not None:
-            shape = (b, 1) if out_heads_axis == 1 else (b, 1, 1)
-            kv_len_b = kv_len.reshape(shape)
-        q_off = None
-        if pos is not None and not prefill_cache:
-            q_off = pos
-        elif chunk_block > 0:
-            # causal q positions = pos + arange(S); shaped to broadcast as
-            # (..., S1, 1) against the per-block column ids in
-            # blocked_attention
-            q_off = pos.reshape(
-                (b, 1, 1) if out_heads_axis == 1 else (b, 1, 1, 1)
+            kv_len_b = None
+            if kv_len is not None:
+                shape = (b, 1) if out_heads_axis == 1 else (b, 1, 1)
+                kv_len_b = kv_len.reshape(shape)
+            q_off = None
+            if pos is not None and not prefill_cache:
+                q_off = pos
+            elif chunk_block > 0:
+                # causal q positions = pos + arange(S); shaped to broadcast as
+                # (..., S1, 1) against the per-block column ids in
+                # blocked_attention
+                q_off = pos.reshape(
+                    (b, 1, 1) if out_heads_axis == 1 else (b, 1, 1, 1)
+                )
+            out = _attend(
+                q5, k5, v5, cfg, causal=causal, kv_len=kv_len_b,
+                q_offset=q_off,
+                decode=decode_path, chunk_block=chunk_block,
             )
-        out = _attend(
-            q5, k5, v5, cfg, causal=causal, kv_len=kv_len_b,
-            q_offset=q_off,
-            decode=decode_path, chunk_block=chunk_block,
-        )
 
     with jax.named_scope("attn_out"):
         out = jnp.moveaxis(

@@ -111,6 +111,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models import attention
 from repro.runtime.paged_cache import (
     NULL_PAGE,
     PageAllocator,
@@ -1608,10 +1609,12 @@ class ServeEngine:
         tel = self.telemetry
         return _NO_PHASE if tel is None else tel.phase(name, self.steps)
 
-    def _gathered_kv(self) -> int:
-        """K/V positions one decode call gathers per layer: every row's
-        whole page table, however few of its positions are live."""
-        return self.max_batch * self.max_pages_per_seq * self.page_size
+    def _kv_slots(self, pos: np.ndarray) -> int:
+        """K/V positions the decode call at write positions ``pos`` reads
+        per layer (``models.attention.paged_decode_reads``)."""
+        return attention.paged_decode_reads(
+            self.bundle.cfg, self.page_size, pos, self.max_pages_per_seq
+        )
 
     def _dispatch(self, live: List[Request]) -> None:
         """The dispatch phase of :meth:`step`: plan this step's prefill
@@ -1747,7 +1750,7 @@ class ServeEngine:
                 sub = np.arange(self.speculate + 1)
                 tel.on_decode(
                     len(dec), int(((pos[:, None] + sub + 1) * active).sum()),
-                    self._gathered_kv() * (self.speculate + 1),
+                    self._kv_slots(np.where(active, pos[:, None] + sub, 0)),
                 )
         else:
             args = [self.params, feed, jnp.asarray(pos), self.pool,
@@ -1762,7 +1765,7 @@ class ServeEngine:
             st.decode_tok = nxt
             if tel is not None:
                 tel.on_decode(
-                    len(dec), int(pos.sum()) + len(dec), self._gathered_kv()
+                    len(dec), int(pos.sum()) + len(dec), self._kv_slots(pos)
                 )
         # keep each decoding slot's sampled output resident on device for
         # the NEXT step's feed; non-decoding slots retain their value.

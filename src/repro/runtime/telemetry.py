@@ -101,8 +101,11 @@ ANNOTATION_PREFIX = "serve."
 #: Work counted where it is dispatched, added to each step's ``engine``
 #: counter sample (zero when the step ran no such call): ``decode_rows``
 #: = rows decoding; ``kv_live`` = K/V positions those rows attend
-#: (cursor + 1 each); ``kv_slots`` = K/V positions the decode call gathers
-#: (max_batch x max_pages_per_seq x page_size, whatever is live);
+#: (cursor + 1 each); ``kv_slots`` = K/V positions the decode call reads
+#: (the XLA route gathers max_batch x max_pages_per_seq x page_size,
+#: whatever is live; the paged kernel reads each slot's pages up to its
+#: kv_len, one null page for an idle slot:
+#: ``models.attention.paged_decode_reads``);
 #: ``prefill_tokens`` = real prompt tokens in the prefill call;
 #: ``prefill_slots`` = its static prefill_batch x prefill_chunk grid.  A
 #: speculative verify counts each of its K+1 decode sub-steps.

@@ -199,6 +199,24 @@ def test_round_f16_matches_ieee_cast():
     assert same.all(), x[~same][:8]
 
 
+def test_bf16_to_f16_matches_ieee_cast():
+    """kernels/fp16.bf16_to_f16 (the fp16 store of a bf16 pool page in
+    compiled kernels) equals ``astype(float16)`` on every bf16 value."""
+    from repro.kernels.fp16 import bf16_to_f16
+
+    x = jnp.asarray(
+        np.arange(2**16, dtype=np.uint16).view(jnp.bfloat16)
+    )
+    with np.errstate(over="ignore"):
+        want = np.asarray(x.astype(jnp.float32)).astype(np.float16)
+    want = want.astype(np.float32)
+    got = np.asarray(jax.jit(bf16_to_f16)(x))
+    same = (got.view(np.uint32) == want.view(np.uint32)) | (
+        np.isnan(got) & np.isnan(want)
+    )
+    assert same.all(), np.asarray(x)[~same][:8]
+
+
 @pytest.fixture
 def emulated_kernels(monkeypatch):
     """Run the compiled-path bodies (fp16 held in f32 containers and rounded

@@ -1,6 +1,7 @@
 """Paged-KV subsystem: allocator invariants, paged decode kernel vs oracles,
 paged-vs-dense serving equivalence, ragged-tail shift conventions."""
 
+import dataclasses
 import math
 
 import jax
@@ -554,6 +555,187 @@ def test_evicted_prefix_pages_are_reused_cleanly(tiny_bundle):
     assert ra2.cached_len == 0
     assert ra2.generated == ra.generated
     assert eng.prefix_cache.stats()["evictions"] == 4
+
+
+def _route_cfg(arch, page, beta=None, **shape):
+    from repro.configs import get_config
+
+    cfg = get_config(arch).reduced()
+    ac = dataclasses.replace(cfg.attention, block_kv=page)
+    if beta is not None:
+        ac = dataclasses.replace(ac, beta=beta)
+    return dataclasses.replace(cfg, attention=ac, **shape)
+
+
+def _serve_route(cfg, route, monkeypatch, page=8, prompts=None,
+                 **engine_kw):
+    """Serve four requests through three slots on one paged decode route:
+    the greedy tokens and the final pool.  Every float page but the null
+    page starts as NaN, so each row reads pages holding NaN past its
+    kv_len (the no-scrub contract); the four requests leave slots idle,
+    recycle the first requests' pages for the last, and walk each row's
+    kv_len through mid-page positions and page boundaries."""
+    from repro.models import attention
+    from repro.models.model_zoo import build
+
+    bundle = build(cfg)
+    params = bundle.init(jax.random.PRNGKey(0))
+    if prompts is None:
+        rng = np.random.default_rng(3)
+        prompts = [
+            (list(rng.integers(0, cfg.vocab_size, n)), gen)
+            for n, gen in ((5, 12), (8, 9), (13, 6), (3, 10))
+        ]
+    monkeypatch.setattr(attention, "_paged_decode_backend", lambda: route)
+    eng = ServeEngine(
+        bundle, params, max_batch=3, num_pages=16, page_size=page,
+        max_seq_len=40, prefill_chunk=16, **engine_kw,
+    )
+    eng.pool = {
+        n: x.at[:, NULL_PAGE + 1:].set(jnp.nan)
+        if jnp.issubdtype(x.dtype, jnp.floating) else x
+        for n, x in eng.pool.items()
+    }
+    reqs = [eng.submit(p, gen) for p, gen in prompts]
+    eng.run_to_completion()
+    assert reqs[-1].admit_step > 0           # waited for freed pages
+    return [r.generated for r in reqs], dict(eng.pool), eng
+
+
+@pytest.mark.parametrize("arch, shape", [
+    ("qwen3-4b", dict(n_heads=8)),                  # group 4, q/k norm
+    ("qwen2-7b", dict(n_heads=28, n_kv_heads=4)),   # group 7, QKV bias
+    ("qwen3-4b", dict(n_heads=8, beta=0.0)),        # no algebraic shift
+])
+def test_decode_routes_serve_the_same_bits(arch, shape, monkeypatch):
+    """The paged decode kernel (run by the Pallas interpreter) and the XLA
+    gather route serve the same greedy tokens and leave the same pool
+    bytes, over NaN-poisoned, recycled pages and idle slots
+    (``_serve_route``); with and without the algebraic shift."""
+    from repro.models import attention
+
+    cfg = _route_cfg(arch, 8, **shape)
+    assert attention.paged_decode_route(cfg, 8) == "xla"    # CPU
+    xla_tokens, xla_pool, _ = _serve_route(cfg, "xla", monkeypatch)
+    tokens, pool, _ = _serve_route(cfg, "interpret", monkeypatch)
+    assert tokens == xla_tokens
+    for name in xla_pool:
+        np.testing.assert_array_equal(
+            np.asarray(pool[name]).view(np.uint16),
+            np.asarray(xla_pool[name]).view(np.uint16),
+        )
+
+
+def test_decode_routes_agree_under_speculation(monkeypatch):
+    """Speculative verify runs the plain decode step once per draft
+    position, so on a TPU it takes the kernel too: with drafts accepted
+    and rolled back, the interpreted kernel and the XLA route serve the
+    same tokens and leave the same pool bytes."""
+    cfg = _route_cfg("qwen2-7b", 8, n_heads=28, n_kv_heads=4)
+    prompts = [([3, 5, 7, 9] * 3, 12), ([11, 12, 13] * 3, 9),
+               ([4, 6] * 6, 8), ([3, 5, 7] * 2, 10)]   # drafts to verify
+    xla_tokens, xla_pool, _ = _serve_route(
+        cfg, "xla", monkeypatch, prompts=prompts, speculate=2
+    )
+    tokens, pool, eng = _serve_route(
+        cfg, "interpret", monkeypatch, prompts=prompts, speculate=2
+    )
+    assert eng.stats()["spec"]["verify_steps"] >= 1
+    assert eng.stats()["spec"]["accepted"] >= 1
+    assert tokens == xla_tokens
+    for name in xla_pool:
+        np.testing.assert_array_equal(
+            np.asarray(pool[name]).view(np.uint16),
+            np.asarray(xla_pool[name]).view(np.uint16),
+        )
+
+
+@pytest.mark.parametrize("dtype", ["int8", "fp8_e4m3"])
+def test_decode_routes_agree_on_quantized_pools(dtype, monkeypatch):
+    """A quantized pool served through ``_paged_decode``'s sidecar glue
+    on the interpreted kernel matches the kernel's own XLA oracle
+    (``paged_decode_xla``, the gather plus the same ``codes * scale +
+    shift`` dequantization) through the same glue, bit for bit: tokens,
+    codes and sidecars.  (Against the XLA route the tokens may part: that
+    route rounds the dequantized K/V through the bf16 compute dtype
+    before fp16, the kernel rounds them to fp16 once.)"""
+    import functools
+
+    import repro.kernels.ops as ops
+
+    cfg = _route_cfg("qwen2-7b", 8, n_heads=28, n_kv_heads=4)
+    tokens, pool, _ = _serve_route(
+        cfg, "interpret", monkeypatch, cache_dtype=dtype
+    )
+    monkeypatch.setattr(ops, "pasa_paged_decode", functools.partial(
+        ops.pasa_paged_decode, use_kernel=False
+    ))
+    ref_tokens, ref_pool, _ = _serve_route(
+        cfg, "interpret", monkeypatch, cache_dtype=dtype
+    )
+    assert tokens == ref_tokens
+    for name in ref_pool:
+        np.testing.assert_array_equal(
+            np.asarray(pool[name]).view(np.uint8),
+            np.asarray(ref_pool[name]).view(np.uint8),
+        )
+
+
+@pytest.mark.parametrize("dtype", ["int8", "fp8_e4m3"])
+def test_paged_decode_glue_reads_each_heads_sidecars(dtype):
+    """``models.attention._paged_decode`` hands a layer's quantized pool
+    to the kernel with each kv head's scale and shift in place: its
+    output equals the oracle run on the pages dequantized outside
+    (``dequantize_kv_page``, then the fp16 cast), bit for bit."""
+    from repro.core import FP16
+    from repro.kernels.pasa_paged_decode import paged_decode_xla
+    from repro.models import attention
+    from repro.runtime.paged_cache import (
+        dequantize_kv_page,
+        quantize_kv_page,
+    )
+
+    cfg = _route_cfg("qwen2-7b", 8, n_heads=28, n_kv_heads=4)
+    kvh, g, hd = cfg.n_kv_heads, cfg.group, cfg.head_dim
+    b, mp, page = 3, 4, 8
+    n_pages = 1 + b * mp
+    rng = np.random.default_rng(5)
+    layer = {}
+    for side in ("k", "v"):
+        raw = rng.standard_normal((n_pages, page, kvh, hd)) * rng.uniform(
+            0.2, 5.0, (1, 1, kvh, 1)
+        ) + rng.standard_normal((1, 1, kvh, hd))
+        codes, scale, shift = quantize_kv_page(
+            jnp.asarray(raw, jnp.float32), jnp.ones((n_pages, page), bool),
+            dtype,
+        )
+        layer[side] = codes.reshape(n_pages, page, kvh * hd)
+        layer[side + "_scale"] = scale
+        layer[side + "_shift"] = shift.reshape(n_pages, kvh * hd)
+    table = jnp.asarray(
+        1 + rng.permutation(b * mp).reshape(b, mp), jnp.int32
+    )
+    kv_len = jnp.asarray([3, 17, 32], jnp.int32)
+    q = jnp.asarray(rng.standard_normal((b, 1, kvh * g, hd)), jnp.bfloat16)
+    got = attention._paged_decode(
+        q, layer, table, kv_len, cfg, interpret=True
+    )
+
+    def deq(side):
+        return dequantize_kv_page(
+            layer[side].reshape(n_pages, page, kvh, hd),
+            layer[side + "_scale"],
+            layer[side + "_shift"].reshape(n_pages, kvh, hd),
+        ).astype(jnp.float16)
+
+    want = paged_decode_xla(
+        q.reshape(b, kvh, g, hd).astype(jnp.float16), deq("k"), deq("v"),
+        table, kv_len, beta=cfg.attention.beta, policy=FP16,
+        block_kv=page,
+    )
+    np.testing.assert_array_equal(
+        np.asarray(got).view(np.uint16), np.asarray(want).view(np.uint16)
+    )
 
 
 def test_engine_admission_is_conservative(tiny_bundle):

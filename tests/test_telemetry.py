@@ -388,6 +388,42 @@ def test_engine_counter_counts_dispatched_work(tiny_bundle):
     }
 
 
+def test_engine_counter_counts_live_pages_on_kernel_route(monkeypatch):
+    """On the paged decode kernel's route ``kv_slots`` counts the pages
+    the kernel reads: each slot's ceil(kv_len / page) pages, kv_len =
+    pos + 1, and one null page for a slot not decoding.  The schedule of
+    the test above, with page == block_kv == 8 so the kernel applies:
+      step 0: B at 5 (1 page) + A not decoding (1)  -> 2 pages = 16;
+      step 1: A at 20 (3 pages) + B's empty slot (1) -> 4 pages = 32;
+      step 2: A at 21 (3 pages) + the empty slot (1) -> 32."""
+    import dataclasses
+
+    from repro.models import attention
+
+    monkeypatch.setattr(
+        attention, "_paged_decode_backend", lambda: "interpret"
+    )
+    cfg = get_config("qwen3-4b").reduced()
+    cfg = dataclasses.replace(
+        cfg, attention=dataclasses.replace(cfg.attention, block_kv=8)
+    )
+    bundle = build(cfg)
+    tel = Telemetry(tracing=True, metrics=False)
+    eng = ServeEngine(
+        bundle, bundle.init(jax.random.PRNGKey(0)), max_batch=2,
+        num_pages=16, page_size=8, max_seq_len=32, prefill_chunk=16,
+        pipeline_depth=0, telemetry=tel,
+    )
+    eng.submit(list(range(1, 21)), 3)
+    eng.submit(list(range(1, 6)), 2)
+    eng.run_to_completion()
+    got = [
+        (e.args["kv_live"], e.args["kv_slots"])
+        for e in tel.tracer.events() if e.kind == "counter"
+    ]
+    assert got == [(6, 16), (21, 32), (22, 32)]
+
+
 def _profiled_serve(bundle, params, prompts, tmp_path, telemetry):
     """A serve under ``jax.profiler``: the host events named ``serve.*``
     as [name, start_ns, end_ns], and the telemetry's spans."""

@@ -11,7 +11,9 @@ compile in this file.
 """
 
 import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -147,25 +149,71 @@ def test_pasa_decode_kernel_compiles(one_chip):
     ))
 
 
-def test_engine_decode_step_compiles_two_layers(one_chip):
-    """The served decode step at published widths, depth cut to 2 layers:
-    bf16 params, a bf16 page pool, page == block_kv == 128."""
-    s = _spec(one_chip)
-    bundle = build(dataclasses.replace(CFG, n_layers=2))
+@pytest.fixture
+def kernel_route(monkeypatch):
+    """The paged decode route a TPU backend takes, on this CPU host."""
+    from repro.models import attention
+
+    monkeypatch.setattr(attention, "_paged_decode_backend", lambda: "kernel")
+
+
+def _compile_decode_step(sharding, cfg, max_batch, max_seq_len):
+    """The served decode step of ``cfg`` cut to 2 layers: bf16 params, a
+    bf16 page pool of ``max_batch`` full rows, page == block_kv."""
+    s = _spec(sharding)
+    bundle = build(dataclasses.replace(cfg, n_layers=2))
     params = jax.tree.map(
         lambda x: s(x.shape, x.dtype),
         jax.eval_shape(bundle.init, jax.random.PRNGKey(0)),
     )
+    max_pages = -(-max_seq_len // PAGE)
     eng = ServeEngine(
-        bundle, params, max_batch=B, num_pages=N_PAGES, page_size=PAGE,
-        max_seq_len=PROMPT + GEN,
+        bundle, params, max_batch=max_batch,
+        num_pages=1 + max_batch * max_pages, page_size=PAGE,
+        max_seq_len=max_seq_len,
     )
     pool = jax.tree.map(lambda x: s(x.shape, x.dtype), eng.pool)
     i32 = lambda *shape: s(shape, jnp.int32)
     compiled = eng._step_fn.lower(
-        params, i32(B), i32(B), pool, i32(B, eng.max_pages_per_seq)
+        params, i32(max_batch), i32(max_batch), pool,
+        i32(max_batch, eng.max_pages_per_seq),
     ).compile()
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert used < V5E_HBM_BYTES
+    return compiled, eng
+
+
+def _widened_kv_buffers(text, eng):
+    """f16 / f32 buffers of the compiled program as large as one layer's
+    page pool or its whole gathered page table, in any layout: what a cast
+    of the whole pool or table leaves behind."""
+    layer_pool = math.prod(eng.pool["k"].shape[1:])
+    table = eng.max_batch * eng.max_pages_per_seq * PAGE * (
+        eng.pool["k"].shape[-1]
+    )
+    return sorted({
+        shape for shape in re.findall(r"\bf(?:16|32)\[([\d,]+)\]", text)
+        if math.prod(int(n) for n in shape.split(",")) >= min(
+            layer_pool, table
+        )
+    })
+
+
+def test_engine_decode_step_compiles_two_layers(one_chip, kernel_route):
+    """qwen3-4b's decode step at the chat cell's shapes (16 rows of 1536
+    positions) runs the Mosaic paged decode kernel and widens no whole
+    pool or page table to f16 or f32."""
+    compiled, eng = _compile_decode_step(one_chip, CFG, 16, 1536)
+    _assert_kernel(compiled)
+    assert _widened_kv_buffers(compiled.as_text(), eng) == []
+
+
+def test_engine_decode_step_compiles_qwen2_widths(one_chip, kernel_route):
+    """qwen2-7b (4 kv heads, group 7, QKV bias) at the long-document
+    cell's shapes (3 rows of 6400 positions): the same."""
+    cfg = dataclasses.replace(get_config("qwen2-7b"), param_dtype="bfloat16")
+    compiled, eng = _compile_decode_step(one_chip, cfg, 3, 6400)
+    _assert_kernel(compiled)
+    assert _widened_kv_buffers(compiled.as_text(), eng) == []
